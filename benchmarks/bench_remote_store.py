@@ -43,6 +43,7 @@ import numpy as np
 from repro.core import IntermediateStore, TSAR, WorkflowExecutor
 from repro.core.backends import LocalFSBackend
 from repro.net import CachingBackend, RemoteBackend, StoreServer
+from repro.runtime import pin_to_cpu
 from repro.sched import ProcessPoolDispatcher, WorkflowService
 
 STEM_NODES = ("prep", "feat")
@@ -146,6 +147,7 @@ def _threaded_round(n_branches: int, cpu_iters: int, wait_s: float, workers: int
 
 def _client_worker(url, idx, n_workers, n_branches, cpu_iters, wait_s, barrier, q):
     """One workflow process: own Client, shared remote pool, its branch slice."""
+    pin_to_cpu()
     try:
         from repro.api import Client
 

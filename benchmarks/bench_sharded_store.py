@@ -50,6 +50,7 @@ from pathlib import Path
 from repro.core import IntermediateStore
 from repro.core.backends import LocalFSBackend, MemoryBackend
 from repro.net import HashRing, ShardedBackend, StoreServer
+from repro.runtime import pin_to_cpu
 
 from benchmarks.bench_remote_store import (  # shared GIL-bound module zoo
     STEM_NODES,
@@ -87,6 +88,7 @@ class _SerialDiskBackend(MemoryBackend):
 def _sim_shard_main(op_latency_s: float, port_q) -> None:
     """One simulated-disk shard server in its own process — the servers must
     not share the measuring client's GIL, or the round measures Python."""
+    pin_to_cpu()
     srv = StoreServer(_SerialDiskBackend(op_latency_s)).start()
     port_q.put(srv.port)
     signal.signal(signal.SIGTERM, lambda *_: srv.stop())
@@ -160,6 +162,7 @@ def _throughput_round(
 # -- round 2: kill-one-shard durability (real processes) ----------------------
 def _shard_main(root: str, port_q) -> None:
     """One shard server process over its own root directory."""
+    pin_to_cpu()
     srv = StoreServer(LocalFSBackend(root)).start()
     port_q.put((os.getpid(), srv.port))  # pid maps the port back to the proc
     signal.signal(signal.SIGTERM, lambda *_: srv.stop())
@@ -168,6 +171,7 @@ def _shard_main(root: str, port_q) -> None:
 
 def _branch_worker(urls, idx, n_workers, n_branches, cpu_iters, wait_s, barrier, q):
     """One fan-out client against the (degraded) cluster: its branch slice."""
+    pin_to_cpu()
     try:
         from repro.api import Client
 

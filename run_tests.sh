@@ -3,4 +3,4 @@
 # jax + numpy + pytest only — no zstandard, no hypothesis.
 set -eu
 cd "$(dirname "$0")"
-PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}" exec python -m pytest -x -q "$@"
+JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}" exec python -m pytest -x -q "$@"

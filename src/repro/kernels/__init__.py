@@ -1,8 +1,9 @@
 """Pallas TPU kernels for the perf-critical compute layers.
 
 Each kernel ships kernel.py (pl.pallas_call + BlockSpec), ops.py (jit'd
-wrapper), ref.py (pure-jnp oracle).  On CPU the wrappers run interpret=True;
-on TPU they compile via Mosaic.
+wrapper), ref.py (pure-jnp oracle).  The wrappers compile via Mosaic for
+the TPU; ``interpret=True`` runs them in the Pallas interpreter instead,
+which is how the CPU tests call them.
 """
 from .decode_attention import decode_attention, decode_attention_ref
 from .embedding_bag import embedding_bag, embedding_bag_ref

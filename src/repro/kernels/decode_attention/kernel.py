@@ -3,7 +3,9 @@ long KV cache, online softmax over KV blocks, variable per-sequence lengths
 delivered via scalar prefetch (SMEM on TPU).
 
 Grid (B, Hkv, n_kv_blocks): KV innermost/sequential; the per-(b,h) state is
-the grouped-query accumulator (G, d) in VMEM scratch.
+the grouped-query accumulator (G, d) in VMEM scratch.  The cache is taken
+head-major, [B, Hkv, T, d], so each block's last two dims are (block_k, d):
+Mosaic tiles the last two dims of a block and refuses a (1, d) head slice.
 """
 from __future__ import annotations
 
@@ -14,17 +16,16 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .._compat import CompilerParams
 
 MASK_VALUE = -0.7 * float(jnp.finfo(jnp.float32).max)
 
 
 def _decode_kernel(
     lens_ref,  # scalar-prefetch: [B] int32 (valid length incl. current token)
-    q_ref,  # [1, 1, G, d]
-    k_ref,  # [1, blk_k, 1, d]
+    q_ref,  # [G, d]
+    k_ref,  # [blk_k, d]
     v_ref,
-    o_ref,  # [1, 1, G, d]
+    o_ref,  # [G, d]
     m_scr,  # [G]
     l_scr,  # [G]
     acc_scr,  # [G, d]
@@ -42,9 +43,9 @@ def _decode_kernel(
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[0, 0, :, :]  # [G, d]
-    k = k_ref[0, :, 0, :]  # [blk_k, d]
-    v = v_ref[0, :, 0, :]
+    q = q_ref[...]
+    k = k_ref[...]
+    v = v_ref[...]
 
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
@@ -66,14 +67,14 @@ def _decode_kernel(
 
     @pl.when(ki == n_k - 1)
     def _done():
-        o_ref[0, 0, :, :] = (
+        o_ref[...] = (
             acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)[:, None]
         ).astype(o_ref.dtype)
 
 
 def decode_attention_kernel(
     q: jax.Array,  # [B, Hkv, G, d]
-    k_cache: jax.Array,  # [B, T, Hkv, d]  (T padded to block multiple)
+    k_cache: jax.Array,  # [B, Hkv, T, d]  (T padded to block multiple)
     v_cache: jax.Array,
     lens: jax.Array,  # [B] int32
     *,
@@ -81,7 +82,7 @@ def decode_attention_kernel(
     interpret: bool = False,
 ) -> jax.Array:
     B, Hkv, G, d = q.shape
-    T = k_cache.shape[1]
+    T = k_cache.shape[2]
     n_k = T // block_k
     scale = 1.0 / (d ** 0.5)
 
@@ -92,11 +93,17 @@ def decode_attention_kernel(
         num_scalar_prefetch=1,
         grid=(B, Hkv, n_k),
         in_specs=[
-            pl.BlockSpec((1, 1, G, d), lambda b, h, ki, lens: (b, h, 0, 0)),
-            pl.BlockSpec((1, block_k, 1, d), lambda b, h, ki, lens: (b, ki, h, 0)),
-            pl.BlockSpec((1, block_k, 1, d), lambda b, h, ki, lens: (b, ki, h, 0)),
+            pl.BlockSpec((None, None, G, d), lambda b, h, ki, lens: (b, h, 0, 0)),
+            pl.BlockSpec(
+                (None, None, block_k, d), lambda b, h, ki, lens: (b, h, ki, 0)
+            ),
+            pl.BlockSpec(
+                (None, None, block_k, d), lambda b, h, ki, lens: (b, h, ki, 0)
+            ),
         ],
-        out_specs=pl.BlockSpec((1, 1, G, d), lambda b, h, ki, lens: (b, h, 0, 0)),
+        out_specs=pl.BlockSpec(
+            (None, None, G, d), lambda b, h, ki, lens: (b, h, 0, 0)
+        ),
         scratch_shapes=[
             pltpu.VMEM((G,), jnp.float32),
             pltpu.VMEM((G,), jnp.float32),
@@ -107,7 +114,7 @@ def decode_attention_kernel(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, d), q.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

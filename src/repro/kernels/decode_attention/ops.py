@@ -17,19 +17,18 @@ def decode_attention(
     lens: jax.Array,  # [B] valid cache length incl. current token
     *,
     block_k: int = 512,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ) -> jax.Array:
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     B, Hq, d = q.shape
     T, Hkv = k_cache.shape[1], k_cache.shape[2]
     G = Hq // Hkv
     block_k = min(block_k, T)
     pad = (-T) % block_k
-    if pad:
-        widths = [(0, 0), (0, pad), (0, 0), (0, 0)]
-        k_cache = jnp.pad(k_cache, widths)
-        v_cache = jnp.pad(v_cache, widths)
+    # head-major [B, Hkv, T, d]: the kernel's blocks are (block_k, d) tiles
+    k_cache, v_cache = (
+        jnp.pad(x, [(0, 0), (0, pad), (0, 0), (0, 0)]).transpose(0, 2, 1, 3)
+        for x in (k_cache, v_cache)
+    )
     qg = q.reshape(B, Hkv, G, d)
     out = decode_attention_kernel(
         qg, k_cache, v_cache, lens.astype(jnp.int32),

@@ -16,10 +16,8 @@ def embedding_bag(
     weights: jax.Array | None = None,
     *,
     combiner: str = "sum",
-    interpret: bool | None = None,
+    interpret: bool = False,
 ) -> jax.Array:
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     V, dim = table.shape
     pad = (-dim) % 128  # TPU lane alignment
     tp = jnp.pad(table, [(0, 0), (0, pad)]) if pad else table

@@ -2,9 +2,11 @@
 
 TPU mapping: grid (B, Hq, n_q_blocks, n_kv_blocks) with the KV dimension
 innermost ("arbitrary" semantics — sequential on TPU), online-softmax
-accumulators (m, l, acc) in VMEM scratch.  Block shapes are (block_q, d) /
-(block_k, d) tiles — MXU-aligned multiples of 128 by default — so the
-working set per step is q + k + v + acc ~ 4 * 128 * d * 4B << VMEM.
+accumulators (m, l, acc) in VMEM scratch.  Operands are head-major,
+[B, H, S, d], so block shapes are (block_q, d) / (block_k, d) tiles —
+MXU-aligned multiples of 128 by default; Mosaic tiles the last two dims of a
+block and refuses a (1, d) head slice — and the working set per step is
+q + k + v + acc ~ 4 * 128 * d * 4B << VMEM.
 """
 from __future__ import annotations
 
@@ -15,7 +17,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .._compat import CompilerParams
 
 MASK_VALUE = -0.7 * float(jnp.finfo(jnp.float32).max)
 
@@ -46,9 +47,9 @@ def _flash_kernel(
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[0, :, 0, :]  # [blk_q, d]
-    k = k_ref[0, :, 0, :]  # [blk_k, d]
-    v = v_ref[0, :, 0, :]  # [blk_k, d]
+    q = q_ref[...]  # [blk_q, d]
+    k = k_ref[...]  # [blk_k, d]
+    v = v_ref[...]  # [blk_k, d]
 
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
@@ -77,14 +78,14 @@ def _flash_kernel(
 
     @pl.when(ki == n_k - 1)
     def _done():
-        o_ref[0, :, 0, :] = (
+        o_ref[...] = (
             acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)[:, None]
         ).astype(o_ref.dtype)
 
 
 def flash_attention_kernel(
-    q: jax.Array,  # [B, Sq, Hq, d]  (padded to block multiples)
-    k: jax.Array,  # [B, Sk, Hkv, d]
+    q: jax.Array,  # [B, Hq, Sq, d]  (padded to block multiples)
+    k: jax.Array,  # [B, Hkv, Sk, d]
     v: jax.Array,
     *,
     causal: bool,
@@ -94,8 +95,8 @@ def flash_attention_kernel(
     block_k: int = 128,
     interpret: bool = False,
 ) -> jax.Array:
-    B, Sq, Hq, d = q.shape
-    _, Sk, Hkv, _ = k.shape
+    B, Hq, Sq, d = q.shape
+    _, Hkv, Sk, _ = k.shape
     group = Hq // Hkv
     n_q = Sq // block_q
     n_k = Sk // block_k
@@ -115,18 +116,24 @@ def flash_attention_kernel(
         kernel,
         grid=(B, Hq, n_q, n_k),
         in_specs=[
-            pl.BlockSpec((1, block_q, 1, d), lambda b, h, qi, ki: (b, qi, h, 0)),
-            pl.BlockSpec((1, block_k, 1, d), lambda b, h, qi, ki: (b, ki, h // group, 0)),
-            pl.BlockSpec((1, block_k, 1, d), lambda b, h, qi, ki: (b, ki, h // group, 0)),
+            pl.BlockSpec((None, None, block_q, d), lambda b, h, qi, ki: (b, h, qi, 0)),
+            pl.BlockSpec(
+                (None, None, block_k, d), lambda b, h, qi, ki: (b, h // group, ki, 0)
+            ),
+            pl.BlockSpec(
+                (None, None, block_k, d), lambda b, h, qi, ki: (b, h // group, ki, 0)
+            ),
         ],
-        out_specs=pl.BlockSpec((1, block_q, 1, d), lambda b, h, qi, ki: (b, qi, h, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, Sq, Hq, d), q.dtype),
+        out_specs=pl.BlockSpec(
+            (None, None, block_q, d), lambda b, h, qi, ki: (b, h, qi, 0)
+        ),
+        out_shape=jax.ShapeDtypeStruct((B, Hq, Sq, d), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((block_q,), jnp.float32),
             pltpu.VMEM((block_q,), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -1,4 +1,4 @@
-"""jit'd wrapper: padding, GQA plumbing, interpret-mode fallback on CPU."""
+"""jit'd wrapper: padding, GQA plumbing and the head-major layout."""
 from __future__ import annotations
 
 from functools import partial
@@ -32,17 +32,16 @@ def flash_attention(
     window: int | None = None,
     block_q: int = 128,
     block_k: int = 128,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ) -> jax.Array:
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     B, Sq, Hq, d = q.shape
     Sk = k.shape[1]
     block_q = min(block_q, max(Sq, 16))
     block_k = min(block_k, max(Sk, 16))
-    qp = _pad_to(q, 1, block_q)
-    kp = _pad_to(k, 1, block_k)
-    vp = _pad_to(v, 1, block_k)
+    # head-major [B, H, S, d]: the kernel's blocks are (block, d) tiles
+    qp = _pad_to(q, 1, block_q).transpose(0, 2, 1, 3)
+    kp = _pad_to(k, 1, block_k).transpose(0, 2, 1, 3)
+    vp = _pad_to(v, 1, block_k).transpose(0, 2, 1, 3)
     out = flash_attention_kernel(
         qp,
         kp,
@@ -54,4 +53,4 @@ def flash_attention(
         block_k=block_k,
         interpret=interpret,
     )
-    return out[:, :Sq]
+    return out.transpose(0, 2, 1, 3)[:, :Sq]

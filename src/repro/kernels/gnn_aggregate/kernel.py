@@ -17,8 +17,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .._compat import CompilerParams
-
 
 def _agg_kernel(nbr_ref, h_ref, gate_ref, o_ref, acc_scr, *, max_deg: int):
     j = pl.program_id(1)
@@ -60,7 +58,7 @@ def gnn_aggregate_kernel(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((N, dim), h.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -15,10 +15,8 @@ def gnn_aggregate(
     nbr: jax.Array,  # [N, max_deg]
     gates: jax.Array,  # [N, max_deg, dim]
     *,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ) -> jax.Array:
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     N, dim = h.shape
     pad = (-dim) % 128
     if pad:

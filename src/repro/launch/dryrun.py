@@ -70,14 +70,6 @@ def apply_cfg_overrides(cfg, overrides: list[str]):
     return dataclasses.replace(cfg, **kw) if kw else cfg
 
 
-def _cost_dict(compiled) -> dict:
-    """compiled.cost_analysis(): dict in jax >= 0.5, [dict] (per device) before."""
-    ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca
-
-
 def lower_cell(
     cfg,
     cell: ShapeCell,
@@ -90,9 +82,7 @@ def lower_cell(
     """Build + lower one (arch x shape) cell on a mesh. Returns lowered."""
     _, params_sds, _, _ = _abstract_state(cfg, cell, mesh)
     in_sds = shard_input_specs(cfg, cell, mesh)
-    from .mesh import mesh_context
-
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         if cell.kind in ("train", "full_graph", "minibatch", "batched_graphs"):
             step = build_train_step(
                 cfg, cell, remat=remat, unroll=unroll, grad_accum=grad_accum
@@ -163,7 +153,7 @@ def run_cell(
     t_compile = time.time() - t0
 
     ma = compiled.memory_analysis()
-    ca = _cost_dict(compiled)
+    ca = compiled.cost_analysis() or {}
     hlo = compiled.as_text()
     coll = analysis.parse_collectives(hlo)
 
@@ -179,7 +169,7 @@ def run_cell(
                 cfg, cell, mesh, unroll=2, remat=remat, grad_accum=grad_accum
             )
             compiled2 = lowered2.compile()
-            ca2 = _cost_dict(compiled2)
+            ca2 = compiled2.cost_analysis() or {}
             coll2 = analysis.parse_collectives(compiled2.as_text())
             flops = analysis.scan_correct(flops1, float(ca2.get("flops", 0.0)), L)
             hbm = analysis.scan_correct(bytes1, float(ca2.get("bytes accessed", 0.0)), L)

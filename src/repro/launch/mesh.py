@@ -8,6 +8,7 @@ from __future__ import annotations
 import math
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False, devices=None) -> jax.sharding.Mesh:
@@ -21,7 +22,9 @@ def make_production_mesh(*, multi_pod: bool = False, devices=None) -> jax.shardi
             f"need {n} devices for mesh {shape}, have {len(devices)} — run under "
             "XLA_FLAGS=--xla_force_host_platform_device_count=512 (dryrun.py sets it)"
         )
-    return jax.make_mesh(shape, axes, devices=devices[:n])
+    return jax.make_mesh(
+        shape, axes, devices=devices[:n], axis_types=(AxisType.Auto,) * len(shape)
+    )
 
 
 def make_smoke_mesh(*, multi_pod: bool = False, devices=None) -> jax.sharding.Mesh:
@@ -31,13 +34,6 @@ def make_smoke_mesh(*, multi_pod: bool = False, devices=None) -> jax.sharding.Me
     n = math.prod(shape)
     if devices is None:
         devices = jax.devices()
-    return jax.make_mesh(shape, axes, devices=devices[:n])
-
-
-def mesh_context(mesh: jax.sharding.Mesh):
-    """``jax.set_mesh(mesh)`` where available (jax >= 0.5); otherwise enter the
-    Mesh directly (the pre-0.5 ambient-mesh context manager)."""
-    set_mesh = getattr(jax, "set_mesh", None) or getattr(
-        jax.sharding, "use_mesh", None
+    return jax.make_mesh(
+        shape, axes, devices=devices[:n], axis_types=(AxisType.Auto,) * len(shape)
     )
-    return set_mesh(mesh) if set_mesh is not None else mesh

@@ -14,6 +14,7 @@ import jax
 from repro.configs import get_config
 from repro.configs.base import ShapeCell
 from repro.models.layers import init_params
+from repro.runtime import enable_compile_cache
 from repro.serve import ServeEngine
 from repro.train import build_param_specs
 
@@ -28,6 +29,7 @@ def main() -> None:
     ap.add_argument("--chunk", type=int, default=16)
     ap.add_argument("--system-len", type=int, default=48)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch, smoke=args.smoke)
     cell = ShapeCell("s", "train", {"seq_len": 16, "global_batch": 1})

@@ -20,7 +20,7 @@ from repro.configs.base import ShapeCell
 from repro.ckpt import CheckpointManager
 from repro.models.layers import init_params, param_count
 from repro.optim import AdamWConfig
-from repro.runtime import TrainDriver
+from repro.runtime import TrainDriver, enable_compile_cache
 from repro.train import build_param_specs, build_train_step, make_train_state
 
 
@@ -37,6 +37,7 @@ def main() -> None:
     ap.add_argument("--grad-accum", type=int, default=1)
     ap.add_argument("--fail-at", type=int, default=None, help="inject failure")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch, smoke=args.smoke)
     if cfg.family != "lm":

@@ -37,13 +37,19 @@ def lm_specs(cfg: LMConfig) -> dict:
         "ln1": PSpec((L, d), ("layers", "embed"), init="zeros"),
         "ln2": PSpec((L, d), ("layers", "embed"), init="zeros"),
     }
+    # The 4-D projections below contract their second axis (for wo the two
+    # head axes), not the second-to-last one init_params assumes.  Scaled by
+    # that wrong fan-in, attention scores reach std ~d_model / heads (128 at
+    # DeepSeek-7B's widths): a softmax so peaked that rounding flips which
+    # key wins.
     if cfg.mla is not None:
         m = cfg.mla
         e_q = m.nope_head_dim + m.rope_head_dim
         layers["attn"] = {
             "w_dq": PSpec((L, d, m.q_lora_rank), ("layers", "embed", "q_lora")),
             "w_uq": PSpec(
-                (L, m.q_lora_rank, hq, e_q), ("layers", "q_lora", "heads", "head_dim")
+                (L, m.q_lora_rank, hq, e_q), ("layers", "q_lora", "heads", "head_dim"),
+                scale=m.q_lora_rank**-0.5,
             ),
             "w_dkv": PSpec(
                 (L, d, m.kv_lora_rank + m.rope_head_dim), ("layers", "embed", "kv_lora")
@@ -51,10 +57,12 @@ def lm_specs(cfg: LMConfig) -> dict:
             "w_uk": PSpec(
                 (L, m.kv_lora_rank, hq, m.nope_head_dim),
                 ("layers", "kv_lora", "heads", "head_dim"),
+                scale=m.kv_lora_rank**-0.5,
             ),
             "w_uv": PSpec(
                 (L, m.kv_lora_rank, hq, m.v_head_dim),
                 ("layers", "kv_lora", "heads", "head_dim"),
+                scale=m.kv_lora_rank**-0.5,
             ),
             "w_o": PSpec(
                 (L, hq * m.v_head_dim, d), ("layers", "qkv", "embed")
@@ -65,10 +73,22 @@ def lm_specs(cfg: LMConfig) -> dict:
             # attn_in/attn_out default to replicated; archs whose head counts
             # don't divide the model axis (gemma3: 8 heads vs 16-way) override
             # them for weight/optimizer STORAGE sharding (weight-gathered)
-            "wq": PSpec((L, d, hq, dh), ("layers", "attn_in", "heads", "head_dim")),
-            "wk": PSpec((L, d, hkv, dh), ("layers", "attn_in", "kv_heads", "head_dim")),
-            "wv": PSpec((L, d, hkv, dh), ("layers", "attn_in", "kv_heads", "head_dim")),
-            "wo": PSpec((L, hq, dh, d), ("layers", "heads", "head_dim", "attn_out")),
+            "wq": PSpec(
+                (L, d, hq, dh), ("layers", "attn_in", "heads", "head_dim"),
+                scale=d**-0.5,
+            ),
+            "wk": PSpec(
+                (L, d, hkv, dh), ("layers", "attn_in", "kv_heads", "head_dim"),
+                scale=d**-0.5,
+            ),
+            "wv": PSpec(
+                (L, d, hkv, dh), ("layers", "attn_in", "kv_heads", "head_dim"),
+                scale=d**-0.5,
+            ),
+            "wo": PSpec(
+                (L, hq, dh, d), ("layers", "heads", "head_dim", "attn_out"),
+                scale=(hq * dh) ** -0.5,
+            ),
         }
     if cfg.moe is not None:
         layers["moe"] = moe_specs(cfg.moe, d, L)

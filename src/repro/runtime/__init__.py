@@ -1,3 +1,4 @@
 from .fault import InjectedFailure, TrainDriver
+from .platform import enable_compile_cache, pin_to_cpu
 
-__all__ = ["InjectedFailure", "TrainDriver"]
+__all__ = ["InjectedFailure", "TrainDriver", "enable_compile_cache", "pin_to_cpu"]

@@ -21,6 +21,8 @@ import multiprocessing
 from concurrent.futures import ProcessPoolExecutor
 from typing import Any, Callable, Mapping, Protocol, runtime_checkable
 
+from ..runtime.platform import pin_to_cpu
+
 
 @runtime_checkable
 class NodeDispatcher(Protocol):
@@ -45,6 +47,7 @@ def _normalize_registry(reg: Any) -> dict[str, Callable[..., Any]]:
 
 def _worker_init(registry_factory: Callable[[], Any]) -> None:
     global _WORKER_FNS
+    pin_to_cpu()  # the coordinating process owns the chip
     _WORKER_FNS = _normalize_registry(registry_factory())
 
 

@@ -72,7 +72,11 @@ def test_dryrun_subprocess_smoke(tmp_path):
             "--out", str(out),
         ],
         cwd=repo,
-        env={"PYTHONPATH": str(repo / "src"), "PATH": "/usr/bin:/bin"},
+        env={
+            "PYTHONPATH": str(repo / "src"),
+            "PATH": "/usr/bin:/bin",
+            "JAX_PLATFORMS": "cpu",
+        },
         capture_output=True,
         text=True,
         timeout=900,

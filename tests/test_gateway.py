@@ -610,7 +610,11 @@ class TestShutdown:
             stdout=subprocess.PIPE,
             stderr=subprocess.STDOUT,
             text=True,
-            env={"PYTHONPATH": repo_src, "PATH": "/usr/bin:/bin"},
+            env={
+                "PYTHONPATH": repo_src,
+                "PATH": "/usr/bin:/bin",
+                "JAX_PLATFORMS": "cpu",
+            },
         )
         try:
             line = proc.stdout.readline()

@@ -86,10 +86,10 @@ def test_moe_ep_annotations_preserve_values():
 
     ref, _ = transformer.forward(params, cfg, tokens)
     # single-device mesh: annotations must be value-neutral
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh(
+        (1, 1), ("data", "model"), axis_types=(jax.sharding.AxisType.Auto,) * 2
+    )
     cfg_a = dataclasses.replace(cfg, moe_ep_axis="model", moe_token_axes=("data",))
-    from repro.launch.mesh import mesh_context
-
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         out, _ = jax.jit(lambda p, t: transformer.forward(p, cfg_a, t))(params, tokens)
     np.testing.assert_allclose(np.asarray(ref), np.asarray(out), atol=1e-5)
