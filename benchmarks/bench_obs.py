@@ -18,7 +18,8 @@ executes (one pre-bound counter inc + one disabled span) must cost **<5%**
 of the hit itself (asserted) — i.e. observability rides along, it never
 taxes reuse.
 
-**Enabled-tracing cost** — per-span cost with NDJSON recording on, and a
+**Enabled-tracing cost** — per-span cost with NDJSON recording on, and with
+the profiler sink alone (a ``jax.profiler`` annotation per span), and a
 ``render_prometheus`` scrape of a fabric-sized registry, reported for
 context (not asserted: recording is opt-in).
 
@@ -91,9 +92,14 @@ def _span_round(smoke: bool) -> tuple[list[str], float]:
             1 for f in os.listdir(d) for _ in open(os.path.join(d, f))
         )
         assert n_lines == reps // 10, "every enabled span must be recorded"
+    # the profiler sink alone: a profiler annotation per span, no file, no ids
+    configure_tracing(None, profiler=True)
+    profiler_s = _per_call(enabled, reps // 10)
+    configure_tracing(None)
     lines = [
         f"obs_span_disabled,{disabled_s * 1e6:.4f},noop fast path (asserted <1us)",
         f"obs_span_enabled,{enabled_s * 1e6:.3f},NDJSON recording on",
+        f"obs_span_profiler,{profiler_s * 1e6:.3f},profiler annotation, no profiler session",
     ]
     return lines, disabled_s
 

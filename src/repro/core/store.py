@@ -24,9 +24,10 @@ import json
 import pickle
 import threading
 import time
+from contextlib import contextmanager
 from dataclasses import dataclass, field
 from pathlib import Path
-from typing import Any, Callable, Sequence
+from typing import Any, Callable, Iterator, Sequence
 
 import numpy as np
 
@@ -130,8 +131,14 @@ class IntermediateStore:
         m = self.metrics
         self._m_puts = m.counter("repro_store_puts_total", "artifacts persisted by the store")
         self._m_gets = m.counter("repro_store_gets_total", "artifact loads served by the store")
+        # put seconds start once the store lock is held; get seconds include
+        # the wait for it.  The lock wait alone, by operation:
         self._m_put_seconds = m.histogram("repro_store_put_seconds", "store put latency")
         self._m_get_seconds = m.histogram("repro_store_get_seconds", "store get latency")
+        lock_wait = m.histogram(
+            "repro_store_lock_wait_seconds", "wait to acquire the store lock", ("op",)
+        )
+        self._m_lock_wait = {op: lock_wait.labels(op=op) for op in ("put", "get", "has")}
         self._m_evictions = m.counter(
             "repro_store_evictions_total", "artifacts deleted by budget eviction"
         )
@@ -156,6 +163,19 @@ class IntermediateStore:
     @property
     def capacity_bytes(self) -> int | None:
         return self.evictor.capacity_bytes
+
+    @contextmanager
+    def _locked(self, op: str) -> Iterator[None]:
+        """Hold ``_lock`` for a ``put``/``get``/``has``; the wait to acquire it
+        is a ``store.lock`` span and a ``repro_store_lock_wait_seconds`` sample."""
+        with _tracing.span("store.lock", kind="store", op=op):
+            t0 = time.perf_counter()
+            self._lock.acquire()
+        self._m_lock_wait[op].observe(time.perf_counter() - t0)
+        try:
+            yield
+        finally:
+            self._lock.release()
 
     # -- index persistence -------------------------------------------------
     def _load_index(self) -> None:
@@ -221,12 +241,15 @@ class IntermediateStore:
         the record nor any policy bookkeeping should be dropped, and
         accounting converges when the pool returns.
         """
-        with self._lock:
-            try:
-                alive = self.backend.exists(key)
-            except BackendUnavailable:
-                return "unreachable"
-            return self._classify_presence(key, alive)
+        with self._locked("has"):
+            return self._has_state_locked(key)
+
+    def _has_state_locked(self, key: str) -> str:
+        try:
+            alive = self.backend.exists(key)
+        except BackendUnavailable:
+            return "unreachable"
+        return self._classify_presence(key, alive)
 
     def has_state_many(self, keys: "Sequence[str]") -> dict[str, str]:
         """Batched :meth:`has_state`: one backend round trip for any number
@@ -238,7 +261,7 @@ class IntermediateStore:
         keys = list(dict.fromkeys(keys))
         if not keys:
             return {}
-        with self._lock:
+        with self._locked("has"):
             try:
                 presence = self.backend.exists_many(keys)
             except BackendUnavailable:
@@ -325,14 +348,22 @@ class IntermediateStore:
     def _write_blob(self, key: str, name: str, arr: np.ndarray) -> int:
         # raw bytes + manifest-recorded dtype/shape: survives ml_dtypes
         # (bfloat16 etc.) that the npy format would degrade to void types
-        blob = self.codec.compress(np.ascontiguousarray(arr).tobytes())
-        return self.backend.write_blob(key, name, blob)
+        with _tracing.span("store.write", kind="store"):
+            blob = self.codec.compress(np.ascontiguousarray(arr).tobytes())
+            return self.backend.write_blob(key, name, blob)
 
     def _read_blob(
         self, key: str, name: str, codec: Codec, dtype: str, shape: list[int]
     ) -> np.ndarray:
-        raw = codec.decompress(self.backend.read_blob(key, name))
+        with _tracing.span("store.read", kind="store"):
+            raw = codec.decompress(self.backend.read_blob(key, name))
         return np.frombuffer(raw, dtype=np.dtype(dtype)).reshape(shape)
+
+    @staticmethod
+    def _fetch(leaf: Any) -> np.ndarray:
+        """A leaf on the host (a device-to-host copy for a device array)."""
+        with _tracing.span("store.fetch", kind="store"):
+            return np.asarray(leaf)
 
     # -- eviction ------------------------------------------------------------
     def add_evict_listener(self, fn: Callable[[str], None]) -> None:
@@ -408,7 +439,7 @@ class IntermediateStore:
         numerator of the eviction criterion.
         """
         with _tracing.span("store.put", kind="store", key=key) as sp:
-            with self._lock:
+            with self._locked("put"):
                 res = self._put_locked(key, value, compute_seconds)
             sp.set(nbytes=res.nbytes_disk, deduped=res.deduped, admitted=res.admitted)
         self._m_puts.inc()
@@ -418,7 +449,7 @@ class IntermediateStore:
     def _put_locked(
         self, key: str, value: Any, compute_seconds: float | None
     ) -> PutResult:
-        if self.has(key):
+        if self._has_state_locked(key) == "present":
             rec = self.records[key]
             if compute_seconds is not None:
                 rec.compute_s = compute_seconds
@@ -451,7 +482,7 @@ class IntermediateStore:
                 entry["dtype"] = str(leaf.dtype)
                 entry["shards"] = []
                 for s in leaf.addressable_shards:
-                    arr = np.asarray(s.data)
+                    arr = self._fetch(s.data)
                     name = self._blob_name(f"leaf{i}.shard{s.device.id}")
                     nbytes_disk += self._write_blob(key, name, arr)
                     nbytes_raw += arr.nbytes
@@ -464,7 +495,7 @@ class IntermediateStore:
                         }
                     )
             else:
-                arr = np.asarray(leaf)
+                arr = self._fetch(leaf)
                 entry["kind"] = "dense"
                 entry["shape"] = list(arr.shape)
                 entry["dtype"] = str(arr.dtype)
@@ -479,8 +510,9 @@ class IntermediateStore:
             self.backend.delete(key)
             return PutResult(key, nbytes_raw, nbytes_disk, 0.0, admitted=False)
 
-        self.backend.write_blob(key, "skeleton.pkl", pickle.dumps(treedef))
-        self.backend.write_blob(key, "manifest.json", json.dumps(manifest).encode())
+        with _tracing.span("store.write", kind="store"):
+            self.backend.write_blob(key, "skeleton.pkl", pickle.dumps(treedef))
+            self.backend.write_blob(key, "manifest.json", json.dumps(manifest).encode())
         dt = time.perf_counter() - t0
         self.records[key] = ArtifactRecord(
             key, nbytes_raw, nbytes_disk, dt, compute_s=compute_seconds
@@ -497,7 +529,7 @@ class IntermediateStore:
     def get(self, key: str, sharding: jax.sharding.Sharding | None = None) -> Any:
         with _tracing.span("store.get", kind="store", key=key) as sp:
             t0 = time.perf_counter()
-            with self._lock:
+            with self._locked("get"):
                 value = self._get_locked(key, sharding)
                 rec = self.records.get(key)
                 compute_s = rec.compute_s if rec is not None else None
@@ -520,13 +552,14 @@ class IntermediateStore:
         # fully-cached get costs ZERO backend round trips (has() would pay an
         # uncacheable exists() probe per call — presence stays authoritative
         # for *planning*, but a load can trust the blob it actually got)
-        try:
-            manifest = json.loads(self.backend.read_blob(key, "manifest.json"))
-        except (KeyError, FileNotFoundError):
-            raise KeyError(key) from None
-        if key not in self.records:
-            self._adopt_record(key)  # stored by a sibling process
-        treedef = pickle.loads(self.backend.read_blob(key, "skeleton.pkl"))
+        with _tracing.span("store.read", kind="store"):
+            try:
+                manifest = json.loads(self.backend.read_blob(key, "manifest.json"))
+            except (KeyError, FileNotFoundError):
+                raise KeyError(key) from None
+            if key not in self.records:
+                self._adopt_record(key)  # stored by a sibling process
+            treedef = pickle.loads(self.backend.read_blob(key, "skeleton.pkl"))
         # pre-codec manifests (seed layout) were always zstd-compressed
         codec = resolve_codec(manifest.get("codec", "zstd"))
         leaves = []
@@ -543,10 +576,11 @@ class IntermediateStore:
                 arr = self._read_blob(
                     key, entry["file"], codec, entry["dtype"], entry["shape"]
                 )
-            if sharding is not None:
-                leaves.append(jax.device_put(arr, sharding))
-            else:
-                leaves.append(jax.numpy.asarray(arr))
+            with _tracing.span("store.upload", kind="store"):
+                if sharding is not None:
+                    leaves.append(jax.device_put(arr, sharding))
+                else:
+                    leaves.append(jax.numpy.asarray(arr))
         value = jax.tree_util.tree_unflatten(treedef, leaves)
         dt = time.perf_counter() - t0
         rec = self.records[key]

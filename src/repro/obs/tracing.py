@@ -22,6 +22,17 @@ no-op object after one module-global check — the hot paths
 (``store.get``, RPC dispatch) pay a function call and a branch, nothing
 else.  ``benchmarks/bench_obs.py`` pins this.
 
+Profiler sink
+-------------
+``configure_tracing(None, profiler=True)`` puts every span opened with
+``with`` on the JAX profiler's clock instead: it enters a
+``jax.profiler.TraceAnnotation`` named ``repro.<span name>`` on its own
+thread and leaves it on exit, so a profiler trace shows the span on the
+``/host:CPU`` plane beside the device's ``XLA Ops``.  With no directory
+nothing is written and no ids are drawn: such spans carry no trace id, so
+they add nothing to the wire either.  Both sinks may be on at once.  JAX is
+imported only when the profiler sink is configured.
+
 Cross-thread propagation is explicit: the current span lives in a
 ``contextvars.ContextVar``, and code that hops threads (scheduler workers)
 re-activates the parent with :func:`activate` or passes ``parent=``.
@@ -46,10 +57,13 @@ __all__ = [
     "current_span",
     "current_traceparent",
     "span",
+    "stitching_enabled",
     "tracing_enabled",
 ]
 
 _HEX = "0123456789abcdef"
+#: prefix of the profiler annotation a span enters (``repro.store.put``)
+PROFILER_PREFIX = "repro."
 
 
 def _rand_hex(nbytes: int) -> str:
@@ -134,6 +148,7 @@ class _SpanWriter:
 # module state: one writer per process; svc can still be overridden per span
 # (in-process test clusters give each StoreServer its own service name)
 _writer: _SpanWriter | None = None
+_annotation: Any = None  # jax.profiler.TraceAnnotation with the profiler sink on
 _enabled = False
 _env_checked = False
 _current: contextvars.ContextVar[Any] = contextvars.ContextVar("repro_span", default=None)
@@ -143,21 +158,30 @@ _baggage: contextvars.ContextVar[Mapping[str, Any]] = contextvars.ContextVar(
 
 
 def configure_tracing(
-    directory: str | None,
+    directory: str | None = None,
     service: str = "repro",
     *,
     enabled: bool = True,
+    profiler: bool = False,
 ) -> None:
-    """Enable (or disable with ``enabled=False``/``directory=None``) span
-    recording for this process.  Also reachable via the ``REPRO_TRACE_DIR``
-    and ``REPRO_SERVICE`` environment variables."""
-    global _writer, _enabled, _env_checked
+    """Enable (or disable with ``enabled=False``, or with neither a
+    ``directory`` nor ``profiler``) span recording for this process.
+
+    ``directory`` records spans as NDJSON there; ``profiler`` puts each span
+    on the JAX profiler's clock as a ``repro.<name>`` annotation.  The
+    NDJSON sink is also reachable via the ``REPRO_TRACE_DIR`` and
+    ``REPRO_SERVICE`` environment variables."""
+    global _writer, _annotation, _enabled, _env_checked
     _env_checked = True
     old = _writer
-    if directory is None or not enabled:
-        _writer, _enabled = None, False
+    _writer = _SpanWriter(directory, service) if enabled and directory is not None else None
+    if enabled and profiler:
+        from jax.profiler import TraceAnnotation
+
+        _annotation = TraceAnnotation
     else:
-        _writer, _enabled = _SpanWriter(directory, service), True
+        _annotation = None
+    _enabled = _writer is not None or _annotation is not None
     if old is not None:
         old.close()
 
@@ -176,6 +200,13 @@ def tracing_enabled() -> bool:
     return _enabled
 
 
+def stitching_enabled() -> bool:
+    """True when spans carry trace ids and are written to stitch into traces
+    (the NDJSON sink); the profiler sink alone needs no ids."""
+    _ensure_env()
+    return _writer is not None
+
+
 class Span:
     __slots__ = (
         "trace_id",
@@ -188,6 +219,7 @@ class Span:
         "_t0",
         "_start",
         "_token",
+        "_ann",
         "_ended",
     )
 
@@ -203,13 +235,15 @@ class Span:
         self.name = name
         self.kind = kind
         self.trace_id = trace_id
-        self.span_id = _rand_hex(8)
+        # a span with no trace (profiler sink only) needs no id of its own
+        self.span_id = _rand_hex(8) if trace_id is not None else None
         self.parent_id = parent_id
         self.svc = svc
         self.attrs = attrs
         self._t0 = time.monotonic()
         self._start = time.time()
         self._token: contextvars.Token | None = None
+        self._ann: Any = None
         self._ended = False
 
     def set(self, **attrs: Any) -> None:
@@ -223,7 +257,7 @@ class Span:
             return
         self._ended = True
         w = _writer
-        if w is None:
+        if w is None or self.trace_id is None:
             return
         w.write(
             {
@@ -242,9 +276,16 @@ class Span:
 
     def __enter__(self) -> "Span":
         self._token = _current.set(self)
+        ann = _annotation
+        if ann is not None:
+            self._ann = ann(PROFILER_PREFIX + self.name)
+            self._ann.__enter__()
         return self
 
     def __exit__(self, exc_type: Any, exc: Any, tb: Any) -> None:
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
         if self._token is not None:
             _current.reset(self._token)
             self._token = None
@@ -297,6 +338,8 @@ def span(
     _ensure_env()
     if not _enabled:
         return NOOP_SPAN
+    if _writer is None:  # profiler sink only: nothing to stitch, no ids
+        return Span(name, kind, None, None, svc, attrs)
     if parent is None:
         parent = _current.get()
     if parent is not None and getattr(parent, "trace_id", None):

@@ -32,6 +32,7 @@ import jax
 from ..core.backends import BackendUnavailable
 from ..core.cost import CostModel
 from ..obs import tracing as _tracing
+from ..obs.metrics import MetricsRegistry
 from ..core.executor import _nbytes, admit_and_store
 from ..core.provenance import ProvenanceLog, RunRecord
 from ..core.registry import ModuleRegistry
@@ -41,6 +42,14 @@ from ..core.workflow import ModuleRef, ModuleSpec, PrefixKey, Workflow
 from .dag import DagWorkflow
 from .dispatch import NodeDispatcher
 from .singleflight import SingleFlight
+
+
+def queue_seconds(registry: MetricsRegistry) -> Any:
+    """``repro_sched_queue_seconds{op}``: a run's wait for a coordinator
+    (``op=run``), a dispatched node's wait for a pool worker (``op=node``)."""
+    return registry.histogram(
+        "repro_sched_queue_seconds", "wait for a free coordinator or worker", ("op",)
+    )
 
 
 class DagWorkflowError(RuntimeError):
@@ -142,6 +151,12 @@ class DagScheduler:
         if self.max_workers < 1:
             raise ValueError("max_workers must be >= 1")
         self.store.add_evict_listener(self._on_store_evict)
+        # the scheduler's series live beside the store's
+        self._m_node_queue = queue_seconds(self.store.metrics).labels(op="node")
+        self._m_compute = self.store.metrics.histogram(
+            "repro_module_compute_seconds",
+            "module function wall time, device work included",
+        )
         self._pool = ThreadPoolExecutor(
             max_workers=self.max_workers, thread_name_prefix="dag-node"
         )
@@ -200,7 +215,8 @@ class DagScheduler:
         with_state = self.policy.with_state
 
         # 1) policy bookkeeping over the path decomposition, then plan
-        rec = self.policy.step_paths(dag.paths())
+        with _tracing.span("sched.policy", kind="run"):
+            rec = self.policy.step_paths(dag.paths())
         chain_prefix = {n: dag.chain_prefix(n) for n in order}
         chain_keys = {
             p.key(with_state): n for n, p in chain_prefix.items() if p is not None
@@ -256,11 +272,13 @@ class DagScheduler:
         while ready or inflight:
             if failure is None:
                 for n in ready:
-                    inflight[self._pool.submit(self._materialize, ctx, n)] = n
+                    fut = self._pool.submit(self._dispatched, ctx, n, time.perf_counter())
+                    inflight[fut] = n
             ready = []
             if not inflight:
                 break
-            done, _ = wait(inflight, return_when=FIRST_COMPLETED)
+            with _tracing.span("sched.wait", kind="run"):
+                done, _ = wait(inflight, return_when=FIRST_COMPLETED)
             for fut in done:
                 n = inflight.pop(fut)
                 try:
@@ -357,6 +375,12 @@ class DagScheduler:
         return result
 
     # -- node production ------------------------------------------------------
+    def _dispatched(self, ctx: _RunCtx, node_id: str, t_queued: float) -> Any:
+        """A pool worker's entry: the wait since dispatch is the node's
+        ``repro_sched_queue_seconds{op=node}`` sample."""
+        self._m_node_queue.observe(time.perf_counter() - t_queued)
+        return self._materialize(ctx, node_id)
+
     def _materialize(self, ctx: _RunCtx, node_id: str) -> Any:
         """Value of ``node_id`` within this run: memo -> single-flight
         load-or-compute -> recursive parent materialization."""
@@ -436,13 +460,14 @@ class DagScheduler:
         params = self._params_for(ref)
         t0 = time.perf_counter()
         try:
-            if self.dispatcher is not None and self.dispatcher.accepts(
-                ref.module_id
-            ):
-                value = self.dispatcher.invoke(ref.module_id, params, inp)
-            else:
-                value = spec.fn(inp, **params)
-            value = jax.block_until_ready(value)
+            with _tracing.span("module.compute", kind="node", module=ref.module_id):
+                if self.dispatcher is not None and self.dispatcher.accepts(
+                    ref.module_id
+                ):
+                    value = self.dispatcher.invoke(ref.module_id, params, inp)
+                else:
+                    value = spec.fn(inp, **params)
+                value = jax.block_until_ready(value)
         except DagWorkflowError:
             raise
         except Exception as e:  # noqa: BLE001 - module code is user code
@@ -450,6 +475,7 @@ class DagScheduler:
                 f"node {node_id!r} ({ref.module_id}) failed: {e}", ctx.dag, node_id, e
             ) from e
         dt = time.perf_counter() - t0
+        self._m_compute.observe(dt)
         assert self.cost_model is not None
         self.cost_model.observe(ref, dt, _nbytes(value))
         with ctx.lock:

@@ -31,7 +31,7 @@ from ..obs import tracing as _tracing
 from ..obs.metrics import MetricsRegistry
 from .dag import DagWorkflow
 from .dispatch import NodeDispatcher
-from .scheduler import DagRunResult, DagScheduler
+from .scheduler import DagRunResult, DagScheduler, queue_seconds
 from .singleflight import SingleFlight
 from .stats import AggregateStats
 
@@ -103,6 +103,7 @@ class WorkflowService:
             catalog=catalog,
         )
         m = self.metrics
+        self._m_run_queue = queue_seconds(m).labels(op="run")
         self._m_runs = m.counter(
             "repro_runs_total", "workflow runs finished", ("status",)
         )
@@ -196,11 +197,11 @@ class WorkflowService:
         not kill the run.
 
         ``trace`` is the run's :class:`~repro.obs.tracing.TraceContext`
-        (gateway-propagated or caller-minted); when tracing is enabled and
-        none is given, a fresh one is minted so every run is traceable.  The
-        returned future carries it as ``fut.trace_id``.
+        (gateway-propagated or caller-minted); when spans are recorded with
+        trace ids and none is given, a fresh one is minted so every run is
+        traceable.  The returned future carries it as ``fut.trace_id``.
         """
-        if trace is None and _tracing.tracing_enabled():
+        if trace is None and _tracing.stitching_enabled():
             trace = _tracing.TraceContext.new()
         fut: Future[DagRunResult] = Future()
         fut.trace_id = trace.trace_id if trace is not None else None  # type: ignore[attr-defined]
@@ -223,8 +224,10 @@ class WorkflowService:
                 pass
 
         wf_name = getattr(dag, "workflow_id", "") or getattr(dag, "dataset_id", "")
+        t_queued = time.perf_counter()
 
         def _coordinate() -> None:
+            self._m_run_queue.observe(time.perf_counter() - t_queued)
             _notify("started")
             rsp = _tracing.span("run", kind="run", parent=trace, workflow=wf_name)
             t0 = time.perf_counter()

@@ -18,6 +18,7 @@ from __future__ import annotations
 import threading
 from typing import Any, Callable
 
+from ..obs import tracing as _tracing
 from ..obs.metrics import MetricsRegistry
 
 
@@ -93,7 +94,8 @@ class SingleFlight:
                 self._m_waits.inc()
                 leader = False
         if not leader:
-            return flight.wait(timeout), False
+            with _tracing.span("flight.wait", kind="node", key=key):
+                return flight.wait(timeout), False
         try:
             value = fn()
         except BaseException as e:

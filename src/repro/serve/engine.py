@@ -56,6 +56,9 @@ class GenStats:
     decode_s: float
     stored_prefixes: int
     n_new_tokens: int
+    # entry to ``generate()`` until the first new token is known (the whole
+    # call where none is asked for)
+    first_token_s: float = 0.0
 
 
 class ServeMetrics:
@@ -89,12 +92,6 @@ class ServeMetrics:
         self.saved = m.counter(
             "repro_serve_prefill_saved_seconds_total",
             "prefill seconds avoided by snapshot reuse (measured cost minus load)",
-        )
-        self.prefill_s = m.histogram(
-            "repro_serve_prefill_seconds", "per-request prefill wall seconds"
-        )
-        self.decode_s = m.histogram(
-            "repro_serve_decode_seconds", "per-request decode wall seconds"
         )
 
 
@@ -162,12 +159,17 @@ class ServeEngine:
         self._chunk_prefill_s = 0.0  # EMA seconds to prefill one chunk
         self._t_first: float | None = None
         self._t_last = 0.0
-        self._prefill = jax.jit(
-            lambda p, t, c, l: transformer.prefill_chunk(p, self.cfg, t, c, l)
-        )
-        self._decode = jax.jit(
-            lambda p, t, c, l: transformer.decode_step(p, self.cfg, t, c, l)
-        )
+        cfg = self.cfg
+
+        # named, so a profiler shows ``jit_prefill_chunk`` / ``jit_decode_step``
+        def prefill_chunk(p, t, c, l):
+            return transformer.prefill_chunk(p, cfg, t, c, l)
+
+        def decode_step(p, t, c, l):
+            return transformer.decode_step(p, cfg, t, c, l)
+
+        self._prefill = jax.jit(prefill_chunk)
+        self._decode = jax.jit(decode_step)
 
     # -- RISP bookkeeping over request chunks ------------------------------
     def _workflow(self, chunks: list[np.ndarray]) -> Workflow:
@@ -277,11 +279,13 @@ class ServeEngine:
             key = prefix.key(ws)
             if prefix.depth in boundary:
                 c, ln = boundary[prefix.depth]
-                if self.snapshots.save(
-                    key, c, ln,
-                    prefill_s=boundary_cost[prefix.depth],
-                    prefix=prefix,
-                ):
+                with _tracing.span("serve.snapshot.save", kind="serve", key=key):
+                    saved = self.snapshots.save(
+                        key, c, ln,
+                        prefill_s=boundary_cost[prefix.depth],
+                        prefix=prefix,
+                    )
+                if saved:
                     stored += 1
                 else:  # budget (or fabric) rejected the snapshot
                     self.policy.stored.pop(key, None)
@@ -303,15 +307,19 @@ class ServeEngine:
     def generate(
         self, prompt: list[int] | np.ndarray, max_new_tokens: int = 16
     ) -> tuple[list[int], GenStats]:
-        prompt = np.asarray(prompt, np.int32)
-        pad = (-len(prompt)) % self.chunk
-        padded = np.concatenate([np.zeros(pad, np.int32), prompt])  # left-pad
-        chunks = [padded[i : i + self.chunk] for i in range(0, len(padded), self.chunk)]
-        n = len(chunks)
-        wf = self._workflow(chunks)
-        rec = self.policy.step(wf)
-        ws = self.policy.with_state
-        depth_keys = {d: wf.prefix(d).key(ws) for d in range(1, n + 1)}
+        t_in = time.perf_counter()
+        with _tracing.span("serve.plan", kind="serve"):
+            prompt = np.asarray(prompt, np.int32)
+            pad = (-len(prompt)) % self.chunk
+            padded = np.concatenate([np.zeros(pad, np.int32), prompt])  # left-pad
+            chunks = [
+                padded[i : i + self.chunk] for i in range(0, len(padded), self.chunk)
+            ]
+            n = len(chunks)
+            wf = self._workflow(chunks)
+            rec = self.policy.step(wf)
+            ws = self.policy.with_state
+            depth_keys = {d: wf.prefix(d).key(ws) for d in range(1, n + 1)}
 
         # the coordination unit: the deepest prefix this request was asked to
         # store — fleet-wide, exactly one engine should prefill it
@@ -364,17 +372,23 @@ class ServeEngine:
         # decode
         t1 = time.perf_counter()
         out: list[int] = []
-        if logits is None:  # full-prompt cache hit: re-run last chunk's logits
-            tok = jnp.asarray(chunks[-1][None], jnp.int32)
-            trimmed_cache, trimmed_len = self._trim_last_chunk(cache, cache_len)
-            logits, cache, cache_len = self._prefill(
-                self.params, tok, trimmed_cache, trimmed_len
-            )
-        for _ in range(max_new_tokens):
-            nxt = int(jnp.argmax(logits[0]))
-            out.append(nxt)
-            tok = jnp.asarray([[nxt]], jnp.int32)
-            logits, cache, cache_len = self._decode(self.params, tok, cache, cache_len)
+        first_token_s = None
+        with _tracing.span("serve.decode", kind="serve"):
+            if logits is None:  # full-prompt cache hit: re-run last chunk's logits
+                tok = jnp.asarray(chunks[-1][None], jnp.int32)
+                trimmed_cache, trimmed_len = self._trim_last_chunk(cache, cache_len)
+                logits, cache, cache_len = self._prefill(
+                    self.params, tok, trimmed_cache, trimmed_len
+                )
+            for _ in range(max_new_tokens):
+                nxt = int(jnp.argmax(logits[0]))
+                if first_token_s is None:
+                    first_token_s = time.perf_counter() - t_in
+                out.append(nxt)
+                tok = jnp.asarray([[nxt]], jnp.int32)
+                logits, cache, cache_len = self._decode(
+                    self.params, tok, cache, cache_len
+                )
         decode_s = time.perf_counter() - t1
 
         stats = GenStats(
@@ -385,6 +399,10 @@ class ServeEngine:
             decode_s=decode_s,
             stored_prefixes=stored,
             n_new_tokens=len(out),
+            first_token_s=(
+                first_token_s if first_token_s is not None
+                else time.perf_counter() - t_in
+            ),
         )
         if self._t_first is None:
             self._t_first = t_pf
@@ -396,8 +414,6 @@ class ServeEngine:
         m.tokens.inc(stats.n_new_tokens)
         m.stored.inc(stats.stored_prefixes)
         m.busy.inc(stats.prefill_s + stats.decode_s)
-        m.prefill_s.observe(stats.prefill_s)
-        m.decode_s.observe(stats.decode_s)
         return out, stats
 
     def _trim_last_chunk(self, cache, cache_len):

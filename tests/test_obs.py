@@ -11,7 +11,9 @@ import io
 import json
 import logging
 import threading
+import time
 
+import numpy as np
 import pytest
 
 from repro.api import Client, WorkflowSpec
@@ -271,6 +273,98 @@ class TestTracing:
         assert by_name["child"]["parent"] == by_name["parent"]["span"]
 
 
+class _Recorder:
+    """Stands in for ``jax.profiler.TraceAnnotation``: logs enter/exit."""
+
+    log: list = []
+
+    def __init__(self, name):
+        self.name = name
+
+    def __enter__(self):
+        self.log.append(("enter", self.name, threading.get_ident()))
+
+    def __exit__(self, *exc):
+        self.log.append(("exit", self.name, threading.get_ident()))
+
+
+class TestProfilerSink:
+    def test_each_span_enters_and_leaves_one_annotation_on_its_thread(
+        self, monkeypatch
+    ):
+        configure_tracing(None, profiler=True)
+        monkeypatch.setattr(tracing, "_annotation", _Recorder)
+        monkeypatch.setattr(_Recorder, "log", [])
+
+        def work(tag):
+            with span(f"outer.{tag}"):
+                with span(f"inner.{tag}"):
+                    pass
+
+        t = threading.Thread(target=work, args=("b",))
+        t.start()
+        t.join()
+        work("a")
+        main = threading.get_ident()
+        assert [(ev, name) for ev, name, tid in _Recorder.log if tid == main] == [
+            ("enter", "repro.outer.a"), ("enter", "repro.inner.a"),
+            ("exit", "repro.inner.a"), ("exit", "repro.outer.a"),
+        ]
+        other = [(ev, name) for ev, name, tid in _Recorder.log if tid != main]
+        assert other == [
+            ("enter", "repro.outer.b"), ("enter", "repro.inner.b"),
+            ("exit", "repro.inner.b"), ("exit", "repro.outer.b"),
+        ]
+
+    def test_without_a_directory_it_writes_nothing_and_draws_no_ids(
+        self, tmp_path, monkeypatch
+    ):
+        monkeypatch.chdir(tmp_path)
+
+        def no_ids(nbytes):
+            raise AssertionError("an id was drawn")
+
+        monkeypatch.setattr(tracing, "_rand_hex", no_ids)
+        configure_tracing(None, profiler=True)
+        assert tracing.tracing_enabled() and not tracing.stitching_enabled()
+        with span("outer") as sp:
+            with span("inner", key="k"):
+                assert current_traceparent() is None
+        assert sp.trace_id is None and sp.span_id is None
+        assert list(tmp_path.iterdir()) == []
+
+    def test_turned_off_span_is_the_noop_again(self):
+        configure_tracing(None, profiler=True)
+        assert span("x") is not NOOP_SPAN
+        configure_tracing(None)
+        assert span("x") is NOOP_SPAN
+        assert tracing._annotation is None
+
+    def test_spans_land_on_the_host_plane_of_a_profiler_trace(self, tmp_path):
+        import jax
+
+        configure_tracing(None, profiler=True)
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with span("store.put"):
+                with span("store.write"):
+                    jax.numpy.ones(4).block_until_ready()
+        finally:
+            jax.profiler.stop_trace()
+        pd = jax.profiler.ProfileData.from_file(
+            str(next(tmp_path.rglob("*.xplane.pb")))
+        )
+        found = {}
+        for plane in pd.planes:
+            for i, line in enumerate(plane.lines):
+                for ev in line.events:
+                    if ev.name.startswith("repro."):
+                        found[ev.name] = (plane.name, i, ev.start_ns, ev.end_ns)
+        outer, inner = found["repro.store.put"], found["repro.store.write"]
+        assert outer[0].startswith("/host:") and outer[:2] == inner[:2]
+        assert outer[2] <= inner[2] <= inner[3] <= outer[3]
+
+
 # -- logging ------------------------------------------------------------------
 
 class TestLogging:
@@ -456,3 +550,82 @@ class TestAliasContract:
             "status", "source", "event", "policy",
         }
         assert len(DEFAULT_BUCKETS) == 14
+
+
+# -- where a workflow's and a request's time goes ------------------------------
+
+def _hist(registry, name, **labels):
+    """(count, sum) of one histogram series."""
+    for s in registry.to_doc()[name]["series"]:
+        if s["labels"] == labels:
+            return s["hist"]["count"], s["hist"]["sum"]
+    return 0, 0.0
+
+
+class _SlowWrites(MemoryBackend):
+    def write_blob(self, key, name, data):
+        time.sleep(0.05)
+        return super().write_blob(key, name, data)
+
+
+class TestWhereTheTimeGoes:
+    def test_store_lock_wait_counts_two_threads_putting_at_once(self):
+        from repro.core import IntermediateStore
+
+        store = IntermediateStore(backend=_SlowWrites())
+        go = threading.Barrier(2)
+
+        def put(i):
+            go.wait()
+            store.put(f"k{i}", np.arange(8.0) + i)
+
+        threads = [threading.Thread(target=put, args=(i,)) for i in range(2)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join()
+        count, waited = _hist(store.metrics, "repro_store_lock_wait_seconds", op="put")
+        assert count == 2
+        # one put held the lock through its three slow writes
+        assert waited > 0.05
+
+    def test_sched_queue_counts_one_run_and_one_node_per_node(self, tmp_path):
+        from repro.core import IntermediateStore, TSAR
+        from repro.sched import WorkflowService
+
+        store = IntermediateStore(tmp_path / "store")
+        svc = WorkflowService(store=store, policy=TSAR(with_state=True), max_workers=2)
+        svc.register_fn("double", lambda x: x * 2)
+        svc.register_fn("inc", lambda x, by=1: x + by, by=1)
+        try:
+            out = svc.run_steps("ds", np.arange(4.0), ["double", "inc", "double"])
+        finally:
+            svc.close()
+        assert out.n_computed == 3
+        m = svc.metrics
+        assert _hist(m, "repro_sched_queue_seconds", op="run")[0] == 1
+        assert _hist(m, "repro_sched_queue_seconds", op="node")[0] == 3
+        count, seconds = _hist(m, "repro_module_compute_seconds")
+        assert count == 3 and seconds > 0
+        assert lint_registry(m) == []
+
+    def test_first_token_lies_within_prefill_and_decode(self):
+        import jax
+
+        from repro.configs import get_config
+        from repro.configs.base import ShapeCell
+        from repro.models.layers import init_params
+        from repro.serve import ServeEngine
+        from repro.train import build_param_specs
+
+        cfg = get_config("tinyllama-1.1b", smoke=True)
+        cell = ShapeCell("t", "train", {"seq_len": 16, "global_batch": 4})
+        params = init_params(jax.random.PRNGKey(1), build_param_specs(cfg, cell), cfg.dtype)
+        eng = ServeEngine(cfg, params, max_len=64, chunk=8)
+        prompt = np.random.default_rng(7).integers(0, cfg.vocab, size=20)
+        eng.generate(prompt, max_new_tokens=2)  # compiles both programs
+        _, st = eng.generate(prompt, max_new_tokens=8)
+        assert st.n_new_tokens == 8
+        assert st.prefill_s < st.first_token_s <= st.prefill_s + st.decode_s
+        assert eng._prefill.__wrapped__.__name__ == "prefill_chunk"
+        assert eng._decode.__wrapped__.__name__ == "decode_step"
