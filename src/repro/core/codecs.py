@@ -15,6 +15,7 @@ cleanly (rather than corrupting) when read on a host without zstandard.
 """
 from __future__ import annotations
 
+import threading
 import zlib
 from dataclasses import dataclass
 from typing import Callable
@@ -86,9 +87,21 @@ try:  # optional dependency: zstd gives ~2x better ratio at similar speed
     import zstandard as _zstd
 
     def _make_zstd(level: int = 3) -> Codec:
-        cctx = _zstd.ZstdCompressor(level=level)
-        dctx = _zstd.ZstdDecompressor()
-        return Codec("zstd", cctx.compress, dctx.decompress, ".zst")
+        # a zstandard context must not be used by two threads at once, and
+        # stores compress and decompress outside their lock: one per thread
+        local = threading.local()
+
+        def compress(b: bytes) -> bytes:
+            if not hasattr(local, "cctx"):
+                local.cctx = _zstd.ZstdCompressor(level=level)
+            return local.cctx.compress(b)
+
+        def decompress(b: bytes) -> bytes:
+            if not hasattr(local, "dctx"):
+                local.dctx = _zstd.ZstdDecompressor()
+            return local.dctx.decompress(b)
+
+        return Codec("zstd", compress, decompress, ".zst")
 
     register_codec(_make_zstd())
     _LEVELED["zstd"] = _make_zstd

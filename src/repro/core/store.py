@@ -122,23 +122,39 @@ class IntermediateStore:
         self._mutations_since_flush = 0
         self._last_flush = time.monotonic()
         self._shared_index_cache: tuple[float, bytes | str | None, dict[str, Any]] | None = None
-        # one reentrant lock serializes index/manifest mutation so concurrent
-        # scheduler workers can't corrupt ``records`` or interleave partial
-        # writes of ``index.json`` (evict listeners run while it is held —
-        # they must not call back into the store or take the policy lock)
+        # one reentrant lock guards ``records``, the index and the eviction
+        # bookkeeping below, so concurrent scheduler workers can't corrupt
+        # ``records`` or interleave partial writes of ``index.json``.  Blob
+        # I/O runs outside it (see ``put``/``get``).  Evict listeners run
+        # while it is held — they must not call back into the store or take
+        # the policy lock.
         self._lock = threading.RLock()
+        # notified when a key leaves ``_writing`` or ``_doomed``
+        self._settled = threading.Condition(self._lock)
+        self._writing: set[str] = set()  # keys a put is writing unlocked
+        self._pins: dict[str, int] = {}  # key -> gets reading it unlocked
+        self._doomed: set[str] = set()  # dropped while pinned: blobs await the last reader
+        self._io_busy = 0  # puts and gets in their unlocked phase
         self.metrics = registry if registry is not None else MetricsRegistry()
         m = self.metrics
         self._m_puts = m.counter("repro_store_puts_total", "artifacts persisted by the store")
         self._m_gets = m.counter("repro_store_gets_total", "artifact loads served by the store")
-        # put seconds start once the store lock is held; get seconds include
-        # the wait for it.  The lock wait alone, by operation:
+        # put seconds cover the whole put except its waits for the store
+        # lock (a dedup put still counts its wait for an in-flight put of the
+        # same key); get seconds include them.  The lock wait alone, by op:
         self._m_put_seconds = m.histogram("repro_store_put_seconds", "store put latency")
         self._m_get_seconds = m.histogram("repro_store_get_seconds", "store get latency")
         lock_wait = m.histogram(
             "repro_store_lock_wait_seconds", "wait to acquire the store lock", ("op",)
         )
         self._m_lock_wait = {op: lock_wait.labels(op=op) for op in ("put", "get", "has")}
+        # at the start of each put's or get's unlocked phase: how many puts
+        # and gets are then in theirs, itself included (above 1: overlap)
+        inflight = m.histogram(
+            "repro_store_io_inflight", "store puts and gets doing blob I/O at once",
+            ("op",), buckets=(1, 2, 3, 4, 6, 8, 12, 16, 32, 64),
+        )
+        self._m_io_inflight = {op: inflight.labels(op=op) for op in ("put", "get")}
         self._m_evictions = m.counter(
             "repro_store_evictions_total", "artifacts deleted by budget eviction"
         )
@@ -165,17 +181,24 @@ class IntermediateStore:
         return self.evictor.capacity_bytes
 
     @contextmanager
-    def _locked(self, op: str) -> Iterator[None]:
+    def _locked(self, op: str) -> Iterator[float]:
         """Hold ``_lock`` for a ``put``/``get``/``has``; the wait to acquire it
-        is a ``store.lock`` span and a ``repro_store_lock_wait_seconds`` sample."""
+        is a ``store.lock`` span and a ``repro_store_lock_wait_seconds``
+        sample, and is what the context yields."""
         with _tracing.span("store.lock", kind="store", op=op):
             t0 = time.perf_counter()
             self._lock.acquire()
-        self._m_lock_wait[op].observe(time.perf_counter() - t0)
+        waited = time.perf_counter() - t0
+        self._m_lock_wait[op].observe(waited)
         try:
-            yield
+            yield waited
         finally:
             self._lock.release()
+
+    def _io_begin(self, op: str) -> None:
+        """A put or get enters its unlocked phase.  Callers hold ``_lock``."""
+        self._io_busy += 1
+        self._m_io_inflight[op].observe(self._io_busy)
 
     # -- index persistence -------------------------------------------------
     def _load_index(self) -> None:
@@ -278,6 +301,8 @@ class IntermediateStore:
     def _classify_presence(self, key: str, alive: bool) -> str:
         """Map one key's backend-reported presence to a ``has_state`` answer,
         applying the bookkeeping side effects.  Callers hold ``_lock``."""
+        if key in self._doomed:
+            return "absent"  # dropped; its blobs wait only for a reader
         if key in self.records:
             if alive:
                 return "present"
@@ -356,8 +381,16 @@ class IntermediateStore:
         self, key: str, name: str, codec: Codec, dtype: str, shape: list[int]
     ) -> np.ndarray:
         with _tracing.span("store.read", kind="store"):
-            raw = codec.decompress(self.backend.read_blob(key, name))
+            raw = codec.decompress(self._blob(key, name))
         return np.frombuffer(raw, dtype=np.dtype(dtype)).reshape(shape)
+
+    def _blob(self, key: str, name: str) -> bytes:
+        """A blob of ``key``; ``KeyError`` where it was never stored or a
+        sibling process sharing the backend deleted it."""
+        try:
+            return self.backend.read_blob(key, name)
+        except (KeyError, FileNotFoundError):
+            raise KeyError(key) from None
 
     @staticmethod
     def _fetch(leaf: Any) -> np.ndarray:
@@ -409,7 +442,7 @@ class IntermediateStore:
         for key in keys:
             rec = self.records.get(key)
             if rec is not None:
-                self.backend.delete(key)
+                self._drop_blobs(key)
                 del self.records[key]
                 self._m_evictions.inc()
                 self._m_evicted_bytes.inc(rec.nbytes_disk)
@@ -417,6 +450,29 @@ class IntermediateStore:
                 fn(key)
         if sp is not None:
             sp.end()
+
+    def _drop_blobs(self, key: str) -> None:
+        """Delete ``key``'s blobs now or, while a get reads them, when its
+        last reader unpins.  Callers hold ``_lock`` and drop the record."""
+        if key in self._pins:
+            self._doomed.add(key)
+        else:
+            self.backend.delete(key)
+
+    def _unpin(self, key: str) -> None:
+        """A get's read of ``key`` is over.  Callers hold ``_lock``."""
+        self._pins[key] -= 1
+        if self._pins[key]:
+            return
+        del self._pins[key]
+        if key in self._doomed:
+            try:
+                self.backend.delete(key)
+            finally:
+                # a delete that raises leaves orphan blobs, never a key that
+                # reads absent and blocks its puts for good
+                self._doomed.discard(key)
+                self._settled.notify_all()
 
     def _enforce_budget(self, incoming: str) -> tuple[str, ...]:
         victims = self.evictor.select_victims(
@@ -437,39 +493,79 @@ class IntermediateStore:
         ``compute_seconds`` is the producer's measured cost of recomputing the
         value (the executor passes the prefix's module seconds) — the *gain*
         numerator of the eviction criterion.
+
+        The store lock is held twice, briefly: to dedup and claim ``key``
+        (a second put of a key being written waits for the first, then
+        dedups), and to commit — write ``manifest.json``, which ``has`` and
+        ``get`` take as the artifact's existence, record it and enforce the
+        budget.
+        Copies to the host, compression and blob writes run between, without
+        it, so puts and gets of other keys proceed meanwhile.  The recorded
+        ``total_disk_bytes`` stays within ``capacity_bytes`` after every put;
+        the disk itself may overshoot by the blobs of the puts in flight.
         """
         with _tracing.span("store.put", kind="store", key=key) as sp:
-            with self._locked("put"):
-                res = self._put_locked(key, value, compute_seconds)
+            t0 = time.perf_counter()
+            with self._locked("put") as waited:
+                res = self._claim(key, compute_seconds)
+            if res is None:
+                res, commit_waited = self._put_claimed(key, value, compute_seconds)
+                waited += commit_waited
             sp.set(nbytes=res.nbytes_disk, deduped=res.deduped, admitted=res.admitted)
         self._m_puts.inc()
-        self._m_put_seconds.observe(res.seconds)
+        self._m_put_seconds.observe(time.perf_counter() - t0 - waited)
         return res
 
-    def _put_locked(
-        self, key: str, value: Any, compute_seconds: float | None
-    ) -> PutResult:
+    def _claim(self, key: str, compute_seconds: float | None) -> PutResult | None:
+        """A dedup result if ``key`` is stored, else ``None`` with ``key``
+        claimed for this put.  Callers hold ``_lock``."""
+        while key in self._writing or key in self._doomed:
+            self._settled.wait()
         if self._has_state_locked(key) == "present":
             rec = self.records[key]
             if compute_seconds is not None:
                 rec.compute_s = compute_seconds
             return PutResult(key, rec.nbytes_raw, rec.nbytes_disk, 0.0, deduped=True)
-        t0 = time.perf_counter()
+        self._writing.add(key)
+        self._io_begin("put")
+        return None
 
+    def _put_claimed(
+        self, key: str, value: Any, compute_seconds: float | None
+    ) -> tuple[PutResult, float]:
+        """Write a claimed put's blobs unlocked, then commit or refuse it
+        under the lock; returns the result and the wait for the lock."""
+        manifest = staged = None
+        try:
+            manifest, staged = self._write_blobs(key, value)
+        finally:
+            with self._locked("put") as waited:
+                self._io_busy -= 1
+                self._writing.discard(key)
+                self._settled.notify_all()
+                if manifest is not None:
+                    staged = self._commit(key, manifest, staged, compute_seconds)
+        return staged, waited
+
+    def _write_blobs(self, key: str, value: Any) -> tuple[dict[str, Any] | None, PutResult]:
+        """Every leaf blob and the skeleton of a claimed put, written without
+        the lock.  Returns the manifest to commit and the sizes and seconds
+        so far, or ``None`` and a refusal (``admitted=False``)."""
+        t0 = time.perf_counter()
         leaves, treedef = jax.tree_util.tree_flatten(value)
         # pre-write admission: an artifact whose RAW size already exceeds the
         # whole budget is rejected before any bytes are compressed or written
         # (compression below 1x would not change the verdict in practice)
         est_raw = sum(int(getattr(leaf, "nbytes", 0) or 0) for leaf in leaves)
         if not self.evictor.admits(est_raw) and self.codec.name == "none":
-            return PutResult(key, est_raw, est_raw, 0.0, admitted=False)
+            return None, PutResult(key, est_raw, est_raw, 0.0, admitted=False)
         if (
             self.evictor.capacity_bytes is not None
             and est_raw > 4 * self.evictor.capacity_bytes
         ):
             # even generous 4x compression could not fit it; don't write 100GB
             # into a 1GB-budget store just to find out
-            return PutResult(key, est_raw, est_raw, 0.0, admitted=False)
+            return None, PutResult(key, est_raw, est_raw, 0.0, admitted=False)
         manifest: dict[str, Any] = {"key": key, "codec": self.codec.name, "leaves": []}
         nbytes_raw = 0
         nbytes_disk = 0
@@ -508,31 +604,67 @@ class IntermediateStore:
         if not self.evictor.admits(nbytes_disk):
             # bigger than the whole budget: storing it could never fit
             self.backend.delete(key)
-            return PutResult(key, nbytes_raw, nbytes_disk, 0.0, admitted=False)
+            return None, PutResult(key, nbytes_raw, nbytes_disk, 0.0, admitted=False)
 
         with _tracing.span("store.write", kind="store"):
             self.backend.write_blob(key, "skeleton.pkl", pickle.dumps(treedef))
+        staged = PutResult(key, nbytes_raw, nbytes_disk, time.perf_counter() - t0)
+        return manifest, staged
+
+    def _commit(
+        self,
+        key: str,
+        manifest: dict[str, Any],
+        staged: PutResult,
+        compute_seconds: float | None,
+    ) -> PutResult:
+        """Publish a written put: its manifest, record and the budget.
+        Callers hold ``_lock``."""
+        t0 = time.perf_counter()
+        with _tracing.span("store.write", kind="store"):
             self.backend.write_blob(key, "manifest.json", json.dumps(manifest).encode())
-        dt = time.perf_counter() - t0
+        dt = staged.seconds + time.perf_counter() - t0
         self.records[key] = ArtifactRecord(
-            key, nbytes_raw, nbytes_disk, dt, compute_s=compute_seconds
+            key, staged.nbytes_raw, staged.nbytes_disk, dt, compute_s=compute_seconds
         )
         evicted = self._enforce_budget(incoming=key)
         self._mark_dirty()
         # a value-aware policy may decide the newcomer itself is the victim:
         # it displaces only artifacts worth less per byte than itself
         return PutResult(
-            key, nbytes_raw, nbytes_disk, dt, admitted=key not in evicted,
-            evicted=evicted,
+            key, staged.nbytes_raw, staged.nbytes_disk, dt,
+            admitted=key not in evicted, evicted=evicted,
         )
 
     def get(self, key: str, sharding: jax.sharding.Sharding | None = None) -> Any:
+        """Load ``key``'s pytree, or raise ``KeyError`` if it is not stored.
+
+        The key is pinned under the lock, read, decompressed and uploaded
+        without it, and its hit statistics updated under it again.  Evicting
+        or deleting a pinned key drops its record at once and its blobs when
+        the last reader unpins, so a get returns a whole value or raises
+        ``KeyError``, never a torn one."""
         with _tracing.span("store.get", kind="store", key=key) as sp:
             t0 = time.perf_counter()
             with self._locked("get"):
-                value = self._get_locked(key, sharding)
-                rec = self.records.get(key)
-                compute_s = rec.compute_s if rec is not None else None
+                if key in self._doomed:
+                    raise KeyError(key)
+                self._pins[key] = self._pins.get(key, 0) + 1
+                self._io_begin("get")
+            loaded = False
+            try:
+                t_read = time.perf_counter()
+                value = self._read_value(key, sharding)
+                load_s = time.perf_counter() - t_read
+                loaded = True
+            finally:
+                with self._locked("get"):
+                    self._io_busy -= 1
+                    try:
+                        compute_s = self._note_load(key, load_s) if loaded else None
+                    finally:
+                        # an index flush that raises must not leave the pin
+                        self._unpin(key)
             dt = time.perf_counter() - t0
             self._m_gets.inc()
             self._m_get_seconds.observe(dt)
@@ -546,20 +678,15 @@ class IntermediateStore:
             sp.set(source="store", saved_s=round(saved, 6))
         return value
 
-    def _get_locked(self, key: str, sharding: jax.sharding.Sharding | None) -> Any:
-        t0 = time.perf_counter()
+    def _read_value(self, key: str, sharding: jax.sharding.Sharding | None) -> Any:
+        """Read a pinned key's blobs into a pytree on the device (no lock)."""
         # optimistic read: the manifest itself is the existence proof, so a
         # fully-cached get costs ZERO backend round trips (has() would pay an
         # uncacheable exists() probe per call — presence stays authoritative
         # for *planning*, but a load can trust the blob it actually got)
         with _tracing.span("store.read", kind="store"):
-            try:
-                manifest = json.loads(self.backend.read_blob(key, "manifest.json"))
-            except (KeyError, FileNotFoundError):
-                raise KeyError(key) from None
-            if key not in self.records:
-                self._adopt_record(key)  # stored by a sibling process
-            treedef = pickle.loads(self.backend.read_blob(key, "skeleton.pkl"))
+            manifest = json.loads(self._blob(key, "manifest.json"))
+            treedef = pickle.loads(self._blob(key, "skeleton.pkl"))
         # pre-codec manifests (seed layout) were always zstd-compressed
         codec = resolve_codec(manifest.get("codec", "zstd"))
         leaves = []
@@ -581,10 +708,17 @@ class IntermediateStore:
                     leaves.append(jax.device_put(arr, sharding))
                 else:
                     leaves.append(jax.numpy.asarray(arr))
-        value = jax.tree_util.tree_unflatten(treedef, leaves)
-        dt = time.perf_counter() - t0
+        return jax.tree_util.tree_unflatten(treedef, leaves)
+
+    def _note_load(self, key: str, load_s: float) -> float | None:
+        """Hit statistics of a finished load; returns the artifact's compute
+        seconds.  Callers hold ``_lock``."""
+        if key in self._doomed:
+            return None  # evicted while it was read: no record to update
+        if key not in self.records:
+            self._adopt_record(key)  # stored by a sibling process
         rec = self.records[key]
-        rec.load_s = dt
+        rec.load_s = load_s
         rec.n_loads += 1
         # deliberately wall-clock (unlike deadline math elsewhere): record
         # timestamps are persisted in index.json and compared across
@@ -594,12 +728,12 @@ class IntermediateStore:
         # restarts of read-only sessions; the batched-flush thresholds bound
         # both the rewrite frequency and the window of lost stats
         self._mark_dirty()
-        return value
+        return rec.compute_s
 
     def delete(self, key: str) -> None:
         with self._lock:
             if key in self.records:
-                self.backend.delete(key)
+                self._drop_blobs(key)
                 del self.records[key]
                 self._mark_dirty()
 

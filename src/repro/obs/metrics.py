@@ -17,9 +17,10 @@ combines docs (optionally stamping each with an extra label such as
 Prometheus text exposition format for the gateway's ``GET /metrics``.
 
 Naming scheme (enforced by :func:`lint_registry` and a tier-1 lint test):
-``repro_<subsystem>_<what>[_unit]``; counters end in ``_total``; label names
-come from the small fixed vocabulary below so dashboards can join across
-subsystems.
+``repro_<subsystem>_<what>[_unit]``; counters end in ``_total``; histograms
+in ``_seconds``, ``_bytes`` or, counting operations in flight, ``_inflight``;
+label names come from the small fixed vocabulary below so dashboards can join
+across subsystems.
 """
 from __future__ import annotations
 
@@ -51,6 +52,10 @@ _LABEL_RE = re.compile(r"^[a-z][a-z0-9_]*$")
 ALLOWED_LABELS = frozenset(
     {"op", "shard", "tenant", "namespace", "dir", "status", "source", "event", "policy"}
 )
+
+#: a histogram's name ends in its base unit, or in ``_inflight`` where one
+#: sample counts the operations in flight at once (a number, no unit)
+_HISTOGRAM_SUFFIXES = ("_seconds", "_bytes", "_inflight")
 
 #: half-decade log buckets, 1e-5 s .. 31.6 s (rounded so bounds are stable
 #: dict keys across processes — a merge requires *identical* bounds)
@@ -377,8 +382,8 @@ def lint_doc(doc: Mapping[str, Any]) -> list[str]:
             problems.append(f"{name}: name does not match {_NAME_RE.pattern}")
         if entry["type"] == "counter" and not name.endswith("_total"):
             problems.append(f"{name}: counter names must end in _total")
-        if entry["type"] == "histogram" and not name.endswith(("_seconds", "_bytes")):
-            problems.append(f"{name}: histogram names must end in _seconds/_bytes")
+        if entry["type"] == "histogram" and not name.endswith(_HISTOGRAM_SUFFIXES):
+            problems.append(f"{name}: histogram names must end in _seconds/_bytes/_inflight")
         if not entry.get("help"):
             problems.append(f"{name}: missing help text")
         for label in entry.get("labels", []):

@@ -585,9 +585,98 @@ class TestWhereTheTimeGoes:
         for t in threads:
             t.join()
         count, waited = _hist(store.metrics, "repro_store_lock_wait_seconds", op="put")
-        assert count == 2
-        # one put held the lock through its three slow writes
-        assert waited > 0.05
+        # each put takes the lock twice: to claim its key, and to commit
+        assert count == 4
+        # leaf and skeleton writes run without the lock: at most one put
+        # waits through the other's manifest write (one slow write), where
+        # a lock held through all three writes would make it wait 0.15 s
+        assert waited < 0.1
+
+        # and a put that finds the lock held records the whole wait: hold
+        # put a's commit (its manifest write runs under the lock) until put
+        # b is known to be blocked on the lock, then release it
+        entered, release, blocked = threading.Event(), threading.Event(), threading.Event()
+
+        class _HoldCommit(MemoryBackend):
+            def write_blob(self, key, name, data):
+                if key == "a" and name == "manifest.json":
+                    entered.set()
+                    assert release.wait(10)
+                return super().write_blob(key, name, data)
+
+        store = IntermediateStore(backend=_HoldCommit())
+        lock = store._lock
+
+        class _TellingLock:
+            """The store's lock, setting ``blocked`` when an acquire must wait."""
+
+            def acquire(self):
+                if lock.acquire(blocking=False):
+                    return True
+                blocked.set()
+                return lock.acquire()
+
+            def release(self):
+                lock.release()
+
+            def __enter__(self):
+                return self.acquire()
+
+            def __exit__(self, *exc):
+                lock.release()
+
+        store._lock = _TellingLock()
+        a = threading.Thread(target=store.put, args=("a", np.arange(8.0)))
+        a.start()
+        try:
+            assert entered.wait(10)
+            b = threading.Thread(target=store.put, args=("b", np.ones(8)))
+            b.start()
+            assert blocked.wait(10)
+            t_blocked = time.perf_counter()
+            time.sleep(0.05)  # how long b waits, not a synchronisation
+            held = time.perf_counter() - t_blocked
+        finally:
+            release.set()
+            a.join()
+        b.join()
+        count, waited = _hist(store.metrics, "repro_store_lock_wait_seconds", op="put")
+        assert count == 4
+        # b's wait began before it was seen blocked and ended after release
+        assert waited >= held >= 0.05
+
+    def test_store_io_inflight_counts_overlapping_puts(self):
+        from repro.core import IntermediateStore
+
+        entered, release = threading.Event(), threading.Event()
+
+        class _HoldA(MemoryBackend):
+            def write_blob(self, key, name, data):
+                if key == "a" and name != "manifest.json":
+                    entered.set()
+                    assert release.wait(10)
+                return super().write_blob(key, name, data)
+
+        store = IntermediateStore(backend=_HoldA())
+        t = threading.Thread(target=store.put, args=("a", np.arange(8.0)))
+        t.start()
+        try:
+            assert entered.wait(10)
+            store.put("b", np.ones(8))  # overlaps a's blob writes
+        finally:
+            release.set()
+            t.join()
+        store.get("a")
+        store.get("b")
+        store.put("b", np.ones(8))  # a dedup has no unlocked phase
+
+        doc = store.metrics.to_doc()["repro_store_io_inflight"]
+        assert doc["labels"] == ["op"]
+        # one sample per put and per get that did blob I/O: a alone (1),
+        # b beside a (2); each get alone (1)
+        assert _hist(store.metrics, "repro_store_io_inflight", op="put") == (2, 3.0)
+        assert _hist(store.metrics, "repro_store_io_inflight", op="get") == (2, 2.0)
+        assert lint_registry(store.metrics) == []
 
     def test_sched_queue_counts_one_run_and_one_node_per_node(self, tmp_path):
         from repro.core import IntermediateStore, TSAR
