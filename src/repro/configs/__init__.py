@@ -7,6 +7,7 @@ from .base import (
     MoESpec,
     RecsysConfig,
     ShapeCell,
+    YarnSpec,
     get_arch,
     get_config,
     get_shapes,
@@ -34,6 +35,7 @@ __all__ = [
     "MoESpec",
     "RecsysConfig",
     "ShapeCell",
+    "YarnSpec",
     "get_arch",
     "get_config",
     "get_shapes",

@@ -78,10 +78,26 @@ RECSYS_SHAPES = (
 # --------------------------------------------------------------------------
 @dataclass(frozen=True)
 class MoESpec:
-    n_experts: int
+    n_experts: int  # routed experts: the router's width
     top_k: int
     n_shared: int = 0
     d_ff_expert: int = 0  # per-expert hidden size
+    # group-limited greedy routing (DeepSeek-V2): experts fall into n_group
+    # equal groups; a token picks its top_k among the topk_group groups whose
+    # best expert scores highest.  n_group=1 is plain top-k.
+    n_group: int = 1
+    topk_group: int = 1
+    norm_topk: bool = True  # renormalise the top-k gates to sum to 1
+    routed_scale: float = 1.0  # gates times this (routed_scaling_factor)
+    # the experts this chip holds: [held_first, held_first + n_held); the
+    # others' share of the output is computed elsewhere (expert parallelism).
+    # n_held=0 holds all n_experts.
+    held_first: int = 0
+    n_held: int = 0
+
+    @property
+    def held(self) -> int:
+        return self.n_held or self.n_experts
 
 
 @dataclass(frozen=True)
@@ -91,6 +107,18 @@ class MLASpec:
     rope_head_dim: int = 64
     nope_head_dim: int = 128
     v_head_dim: int = 128
+
+
+@dataclass(frozen=True)
+class YarnSpec:
+    """YaRN rope scaling (arXiv:2309.00071) as DeepSeek-V2 configures it."""
+
+    factor: float = 40.0
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    original_max_position: int = 4096
+    mscale: float = 0.707
+    mscale_all_dim: float = 0.707
 
 
 @dataclass(frozen=True)
@@ -109,6 +137,9 @@ class LMConfig:
     moe_ep_axis: str | None = None  # mesh axis for explicit EP annotations
     moe_token_axes: tuple = ()
     mla: MLASpec | None = None
+    rope_scaling: YarnSpec | None = None  # MLA's rope dims only
+    # leading dense (SwiGLU of width d_ff) layers before the MoE layers
+    first_k_dense: int = 0
     # local:global attention pattern (gemma3): (n_local, n_global) per cycle
     local_global: tuple[int, int] | None = None
     local_window: int = 1024
@@ -154,16 +185,20 @@ class LMConfig:
             )
         else:
             attn = d * hq * dh + 2 * d * hkv * dh + hq * dh * d
+        if self.mla is not None:
+            attn += self.mla.q_lora_rank + self.mla.kv_lora_rank  # a-norms
         if self.moe is not None:
             ffn_per_layer = (
-                self.moe.n_experts * 3 * d * self.moe.d_ff_expert
+                self.moe.held * 3 * d * self.moe.d_ff_expert
                 + self.moe.n_shared * 3 * d * self.moe.d_ff_expert
                 + d * self.moe.n_experts  # router
             )
         else:
             ffn_per_layer = 3 * d * f
-        per_layer = attn + ffn_per_layer + 2 * d  # 2 rmsnorm scales
-        n = L * per_layer + V * d + d  # embed + final norm
+        k = self.first_k_dense if self.moe is not None else 0
+        norms = 2 * d  # 2 rmsnorm scales
+        n = (L - k) * (attn + ffn_per_layer + norms) + k * (attn + 3 * d * f + norms)
+        n += V * d + d  # embed + final norm
         if not self.tie_embeddings:
             n += V * d
         return int(n)
@@ -171,13 +206,11 @@ class LMConfig:
     def n_active_params(self) -> int:
         if self.moe is None:
             return self.n_params()
-        d, L = self.d_model, self.n_layers
-        dense_like = self.n_params()
-        all_experts = L * self.moe.n_experts * 3 * d * self.moe.d_ff_expert
-        active = L * (self.moe.top_k + self.moe.n_shared) * 3 * d * self.moe.d_ff_expert
-        # shared experts always active; replace routed total by top_k
-        shared = L * self.moe.n_shared * 3 * d * self.moe.d_ff_expert
-        return int(dense_like - all_experts - shared + active)
+        d, L = self.d_model, self.n_layers - self.first_k_dense
+        held = L * self.moe.held * 3 * d * self.moe.d_ff_expert
+        active = L * self.moe.top_k * 3 * d * self.moe.d_ff_expert
+        # shared experts always active; replace the held routed ones by top_k
+        return int(self.n_params() - held + active)
 
 
 @dataclass(frozen=True)
@@ -247,18 +280,9 @@ def _lm_input_specs(cfg: LMConfig, cell: ShapeCell) -> dict[str, jax.ShapeDtypeS
         return {"tokens": _sds((B, S), jnp.int32)}
     if cell.kind == "decode":
         # one new token against a KV cache of length S
-        if cfg.mla is not None:
-            m = cfg.mla
-            cache = {
-                "c_kv": _sds((cfg.n_layers, B, S, m.kv_lora_rank), cfg.dtype),
-                "k_rope": _sds((cfg.n_layers, B, S, m.rope_head_dim), cfg.dtype),
-            }
-        else:
-            hkv, dh = cfg.n_kv_heads, cfg.head_dim
-            cache = {
-                "k": _sds((cfg.n_layers, B, S, hkv, dh), cfg.dtype),
-                "v": _sds((cfg.n_layers, B, S, hkv, dh), cfg.dtype),
-            }
+        from ..models.transformer import init_cache
+
+        cache = jax.eval_shape(lambda: init_cache(cfg, B, S))
         return {
             "tokens": _sds((B, 1), jnp.int32),
             "cache": cache,

@@ -143,6 +143,8 @@ def input_axes(cfg: Config, cell: ShapeCell) -> dict[str, Any]:
                     "k": ("layers", "batch", "kv_seq", "kv_heads", "head_dim"),
                     "v": ("layers", "batch", "kv_seq", "kv_heads", "head_dim"),
                 }
+            if cfg.moe is not None and cfg.first_k_dense:
+                cache = {**cache, "lead": dict(cache)}  # the leading dense layers
             return {"tokens": ("batch", None), "cache": cache, "cache_len": ("batch",)}
     if isinstance(cfg, GNNConfig):
         if cell.kind == "full_graph":

@@ -130,6 +130,52 @@ def apply_rope(x: jax.Array, positions: jax.Array, theta: float = 10_000.0):
     return out.astype(x.dtype)
 
 
+def yarn_mscale(scale: float, mscale: float = 1.0) -> float:
+    """YaRN's attention temperature: 0.1 * mscale * ln(scale) + 1."""
+    return 1.0 if scale <= 1 else 0.1 * mscale * math.log(scale) + 1.0
+
+
+def yarn_freqs(head_dim: int, theta: float, yarn) -> jax.Array:
+    """Inverse frequencies of HF ``DeepseekV2YarnRotaryEmbedding``: the low
+    dimensions keep theta's frequencies, the high ones are divided by
+    ``factor``, with a linear ramp between the correction range that
+    ``beta_fast`` and ``beta_slow`` rotations over the original context set."""
+    def corr_dim(rotations):
+        return (head_dim * math.log(yarn.original_max_position / (rotations * 2 * math.pi))
+                ) / (2 * math.log(theta))
+
+    low = max(math.floor(corr_dim(yarn.beta_fast)), 0)
+    high = min(math.ceil(corr_dim(yarn.beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    extra = rope_freqs(head_dim, theta)
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low) / (high - low), 0, 1)
+    keep = 1.0 - ramp  # 1 where the original frequency is kept
+    return extra / yarn.factor * (1.0 - keep) + extra * keep
+
+
+def apply_rope_interleaved(x: jax.Array, positions: jax.Array, theta: float, yarn=None):
+    """DeepSeek's rope: x [..., S, H, dh] holds its rotary pairs interleaved
+    (x0,x1),(x2,x3)...; they are permuted to halves, then rotated as
+    ``apply_rope`` does, with YaRN frequencies and cos/sin scale where
+    ``yarn`` is given."""
+    dh = x.shape[-1]
+    x = x.reshape(*x.shape[:-1], dh // 2, 2)
+    x = jnp.swapaxes(x, -1, -2).reshape(*x.shape[:-2], dh)
+    if yarn is None:
+        freqs, scale = rope_freqs(dh, theta), 1.0
+    else:
+        freqs = yarn_freqs(dh, theta, yarn)
+        scale = yarn_mscale(yarn.factor, yarn.mscale) / yarn_mscale(
+            yarn.factor, yarn.mscale_all_dim)
+    angles = positions[..., :, None].astype(jnp.float32) * freqs
+    cos = jnp.cos(angles)[..., :, None, :] * scale
+    sin = jnp.sin(angles)[..., :, None, :] * scale
+    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+    return out.astype(x.dtype)
+
+
 # -- embedding / segment ops ---------------------------------------------------
 def embedding_lookup(table: jax.Array, ids: jax.Array) -> jax.Array:
     return jnp.take(table, ids, axis=0)

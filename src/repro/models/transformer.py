@@ -20,18 +20,43 @@ from .attention import (
     gqa_attention,
     insert_chunk,
     insert_kv,
+    mla_chunk_attend,
     mla_decode,
     mla_prefill,
+    mla_project,
+    mla_softmax_scale,
+    put_rows,
 )
 from .layers import PSpec, apply_rope, rms_norm
-from .moe import MoEDims, moe_ffn, moe_specs
+from .moe import EXPERT_WEIGHTS, MoEDims, moe_ffn, moe_ffn_dropless, moe_specs
 
 
 # --------------------------------------------------------------------------
 # param specs
 # --------------------------------------------------------------------------
+def n_lead(cfg: LMConfig) -> int:
+    """Leading dense layers before the MoE layers (DeepSeek's
+    ``first_k_dense_replace``); they live in ``params["lead"]`` and
+    ``cache["lead"]``, stacked apart from the MoE layers' scan."""
+    return cfg.first_k_dense if cfg.moe is not None else 0
+
+
 def lm_specs(cfg: LMConfig) -> dict:
-    L, d, V = cfg.n_layers, cfg.d_model, cfg.vocab
+    k = n_lead(cfg)
+    specs = {
+        "embed": PSpec((cfg.vocab, cfg.d_model), ("vocab", "embed"), scale=0.02),
+        "layers": _layer_specs(cfg, cfg.n_layers - k, moe=cfg.moe is not None),
+        "final_norm": PSpec((cfg.d_model,), ("embed",), init="zeros"),
+    }
+    if k:
+        specs["lead"] = _layer_specs(cfg, k, moe=False)
+    if not cfg.tie_embeddings:
+        specs["head"] = PSpec((cfg.d_model, cfg.vocab), ("embed", "vocab"))
+    return specs
+
+
+def _layer_specs(cfg: LMConfig, L: int, *, moe: bool) -> dict:
+    d = cfg.d_model
     hq, hkv, dh, f = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, cfg.d_ff
     layers: dict[str, Any] = {
         "ln1": PSpec((L, d), ("layers", "embed"), init="zeros"),
@@ -47,6 +72,7 @@ def lm_specs(cfg: LMConfig) -> dict:
         e_q = m.nope_head_dim + m.rope_head_dim
         layers["attn"] = {
             "w_dq": PSpec((L, d, m.q_lora_rank), ("layers", "embed", "q_lora")),
+            "q_norm": PSpec((L, m.q_lora_rank), ("layers", "q_lora"), init="zeros"),
             "w_uq": PSpec(
                 (L, m.q_lora_rank, hq, e_q), ("layers", "q_lora", "heads", "head_dim"),
                 scale=m.q_lora_rank**-0.5,
@@ -54,6 +80,7 @@ def lm_specs(cfg: LMConfig) -> dict:
             "w_dkv": PSpec(
                 (L, d, m.kv_lora_rank + m.rope_head_dim), ("layers", "embed", "kv_lora")
             ),
+            "kv_norm": PSpec((L, m.kv_lora_rank), ("layers", "kv_lora"), init="zeros"),
             "w_uk": PSpec(
                 (L, m.kv_lora_rank, hq, m.nope_head_dim),
                 ("layers", "kv_lora", "heads", "head_dim"),
@@ -90,7 +117,7 @@ def lm_specs(cfg: LMConfig) -> dict:
                 scale=(hq * dh) ** -0.5,
             ),
         }
-    if cfg.moe is not None:
+    if moe:
         layers["moe"] = moe_specs(cfg.moe, d, L)
     else:
         layers["ffn"] = {
@@ -98,14 +125,7 @@ def lm_specs(cfg: LMConfig) -> dict:
             "w_up": PSpec((L, d, f), ("layers", "embed", "ff")),
             "w_down": PSpec((L, f, d), ("layers", "ff", "embed")),
         }
-    specs = {
-        "embed": PSpec((V, d), ("vocab", "embed"), scale=0.02),
-        "layers": layers,
-        "final_norm": PSpec((d,), ("embed",), init="zeros"),
-    }
-    if not cfg.tie_embeddings:
-        specs["head"] = PSpec((d, V), ("embed", "vocab"))
-    return specs
+    return layers
 
 
 def layer_flags(cfg: LMConfig) -> np.ndarray:
@@ -120,16 +140,23 @@ def layer_flags(cfg: LMConfig) -> np.ndarray:
 
 def _moe_dims(cfg: LMConfig) -> MoEDims:
     assert cfg.moe is not None
+    m = cfg.moe
     return MoEDims(
-        n_experts=cfg.moe.n_experts,
-        top_k=cfg.moe.top_k,
-        n_shared=cfg.moe.n_shared,
+        n_experts=m.n_experts,
+        top_k=m.top_k,
+        n_shared=m.n_shared,
         d_model=cfg.d_model,
-        d_ff=cfg.moe.d_ff_expert,
+        d_ff=m.d_ff_expert,
         group_size=cfg.moe_group_size,
         capacity_factor=cfg.moe_capacity_factor,
         ep_axis=cfg.moe_ep_axis,
         token_axes=tuple(cfg.moe_token_axes),
+        n_group=m.n_group,
+        topk_group=m.topk_group,
+        norm_topk=m.norm_topk,
+        routed_scale=m.routed_scale,
+        held_first=m.held_first,
+        n_held=m.n_held,
     )
 
 
@@ -150,6 +177,7 @@ def _attn_block(cfg: LMConfig, p: dict, x, positions, is_global, *, causal=True)
             v_dim=m.v_head_dim,
             positions=positions,
             theta=cfg.rope_theta,
+            yarn=cfg.rope_scaling,
             causal=causal,
             attn_impl=cfg.attention_impl,
             block_q=cfg.attn_block_q,
@@ -175,22 +203,93 @@ def _attn_block(cfg: LMConfig, p: dict, x, positions, is_global, *, causal=True)
     return out, (k, v)
 
 
-def _ffn_block(cfg: LMConfig, layer_p: dict, x):
-    if cfg.moe is not None:
-        return moe_ffn(x, layer_p["moe"], _moe_dims(cfg))
+def _ffn_block(cfg: LMConfig, layer_p: dict, x, dispatch: str = "dropless"):
+    """Returns (out, hits): hits [held + 1] int32 counts a dropless MoE
+    layer's assignments per held expert (last: held elsewhere), else None.
+    ``dispatch`` picks the MoE layer's: "dropless" or "capacity" (GShard)."""
+    if "moe" in layer_p:
+        if dispatch == "capacity":
+            return moe_ffn(x, layer_p["moe"], _moe_dims(cfg)), None
+        return moe_ffn_dropless(x, layer_p["moe"], _moe_dims(cfg))
     f = layer_p["ffn"]
     g = jax.nn.silu(jnp.einsum("bsd,df->bsf", x, f["w_gate"]))
     u = jnp.einsum("bsd,df->bsf", x, f["w_up"])
-    return jnp.einsum("bsf,fd->bsd", g * u, f["w_down"])
+    return jnp.einsum("bsf,fd->bsd", g * u, f["w_down"]), None
 
 
-def _layer(cfg: LMConfig, layer_p: dict, x, positions, is_global, *, collect_kv=False):
+def _layer(cfg: LMConfig, layer_p: dict, x, positions, is_global, *, collect_kv=False,
+           moe_dispatch="dropless"):
     h, kv = _attn_block(
         cfg, layer_p["attn"], rms_norm(x, layer_p["ln1"]), positions, is_global
     )
     x = x + h
-    x = x + _ffn_block(cfg, layer_p, rms_norm(x, layer_p["ln2"]))
+    x = x + _ffn_block(cfg, layer_p, rms_norm(x, layer_p["ln2"]), moe_dispatch)[0]
     return x, (kv if collect_kv else None)
+
+
+def _stacks(cfg: LMConfig, params: dict, cache: dict | None = None) -> list:
+    """(layer params, cache, per-layer flags) of the leading dense layers,
+    if any, then of the main stack."""
+    k = n_lead(cfg)
+    flags = layer_flags(cfg)
+    main = None if cache is None else {n: c for n, c in cache.items() if n != "lead"}
+    out = [(params["layers"], main, flags[k:])]
+    if k:
+        out.insert(0, (params["lead"], None if cache is None else cache["lead"], flags[:k]))
+    return out
+
+
+def _scan_steps(cfg: LMConfig, params: dict, cache: dict, body, x, unroll: int):
+    """Scan a cached step's ``body`` over every stack; returns (x, new cache,
+    the main stack's route counts or None)."""
+    new_cache, hits = {}, None
+    for layer_p, layer_cache, flags in _stacks(cfg, params, cache):
+        step, xs = body, (layer_p, layer_cache, jnp.asarray(flags))
+        if "moe" in layer_p:
+            # the held experts' weights stay whole, indexed by layer inside
+            # (``moe.expert_weights``); the scan slices the rest
+            moe = layer_p["moe"]
+            whole = {k: moe[k] for k in EXPERT_WEIGHTS}
+            rest = {k: v for k, v in moe.items() if k not in whole}
+            xs = ({**layer_p, "moe": rest}, *xs[1:], jnp.arange(len(flags)))
+
+            def step(carry, xs, _whole=whole):
+                lp, lc, is_global, i = xs
+                return body(carry, ({**lp, "moe": {**lp["moe"], **_whole, "layer": i}},
+                                    lc, is_global))
+
+        x, (c, hits) = jax.lax.scan(step, x, xs, unroll=unroll)
+        if layer_p is params["layers"]:
+            new_cache.update(c)
+        else:
+            new_cache["lead"] = c
+    return x, new_cache, hits
+
+
+def _mla_step(cfg: LMConfig, layer_p: dict, x, lc: dict, cache_len, positions, attend,
+              dispatch: str = "dropless"):
+    """One MLA layer of a cached step: project, write this step's latents
+    into the layer's cache, attend with ``attend``, then the FFN."""
+    m = cfg.mla
+    ap = layer_p["attn"]
+    q, new_ckv, new_krope = mla_project(
+        rms_norm(x, layer_p["ln1"]), ap, rope=m.rope_head_dim, positions=positions,
+        theta=cfg.rope_theta, yarn=cfg.rope_scaling,
+    )
+    c_kv_c = put_rows(lc["c_kv"], new_ckv, cache_len)
+    k_rope_c = put_rows(lc["k_rope"], new_krope, cache_len)
+    scale = mla_softmax_scale(m.nope_head_dim + m.rope_head_dim, cfg.rope_scaling)
+    out = attend(q, ap, c_kv_c, k_rope_c, cache_len, scale=scale)
+    y = x + jnp.einsum("bse,ed->bsd", out, ap["w_o"])
+    f, hits = _ffn_block(cfg, layer_p, rms_norm(y, layer_p["ln2"]), dispatch)
+    return y + f, ({"c_kv": c_kv_c, "k_rope": k_rope_c}, hits)
+
+
+def _logits(params: dict, x) -> jax.Array:
+    head = params.get("head")
+    if head is None:
+        return jnp.einsum("bsd,vd->bsv", x, params["embed"])
+    return jnp.einsum("bsd,dv->bsv", x, head)
 
 
 # --------------------------------------------------------------------------
@@ -206,13 +305,15 @@ def forward(
     collect_kv: bool = False,
     last_only: bool = False,
     no_head: bool = False,
+    moe_dispatch: str = "dropless",
 ):
     """tokens [B,S] -> (logits [B,S,V] fp32, cache pytree | None).
-    With no_head=True returns the final hidden states instead of logits."""
+    With no_head=True returns the final hidden states instead of logits.
+    MoE layers take the dropless dispatch unless ``moe_dispatch`` is
+    "capacity" (the GShard dispatch of the training step and the dry-run)."""
     B, S = tokens.shape
     x = jnp.take(params["embed"], tokens, axis=0).astype(cfg.dtype)
     positions = jnp.broadcast_to(jnp.arange(S)[None], (B, S))
-    flags = jnp.asarray(layer_flags(cfg))
 
     def _carry_constraint(y):
         if not (cfg.act_batch_axes or cfg.act_seq_axes):
@@ -228,23 +329,24 @@ def forward(
 
     def body(carry, xs):
         layer_p, is_global = xs
-        y, kv = _layer(cfg, layer_p, carry, positions, is_global, collect_kv=collect_kv)
+        y, kv = _layer(cfg, layer_p, carry, positions, is_global, collect_kv=collect_kv,
+                       moe_dispatch=moe_dispatch)
         return _carry_constraint(y), kv
 
     if remat is not None:
         body = jax.checkpoint(body, policy=remat)
-    x, caches = jax.lax.scan(body, x, (params["layers"], flags), unroll=unroll)
+    caches = []
+    for layer_p, _, flags in _stacks(cfg, params):
+        x, kv = jax.lax.scan(body, x, (layer_p, jnp.asarray(flags)), unroll=unroll)
+        caches.append(kv)
+    if len(caches) == 1:
+        caches = caches[0]
     x = rms_norm(x, params["final_norm"])
     if last_only:
         x = x[:, -1:]  # vLLM-style: prefill only needs the last position
     if no_head:
         return x, caches
-    head = params.get("head")
-    if head is None:
-        logits = jnp.einsum("bsd,vd->bsv", x, params["embed"])
-    else:
-        logits = jnp.einsum("bsd,dv->bsv", x, head)
-    return logits.astype(jnp.float32), caches
+    return _logits(params, x).astype(jnp.float32), caches
 
 
 def streaming_ce_loss(
@@ -285,17 +387,20 @@ def streaming_ce_loss(
     return (jnp.log(s) + m - tgt).mean()
 
 
-def prefill(params: dict, cfg: LMConfig, tokens: jax.Array, *, unroll: int = 1):
+def prefill(params: dict, cfg: LMConfig, tokens: jax.Array, *, unroll: int = 1,
+            moe_dispatch: str = "dropless"):
     """Returns (last-position logits [B,V], cache dict, cache_len [B])."""
     B, S = tokens.shape
     logits, caches = forward(
         params, cfg, tokens, unroll=unroll, collect_kv=True,
-        last_only=cfg.prefill_last_only,
+        last_only=cfg.prefill_last_only, moe_dispatch=moe_dispatch,
     )
-    if cfg.mla is not None:
-        cache = {"c_kv": caches[0], "k_rope": caches[1]}
+    names = ("c_kv", "k_rope") if cfg.mla is not None else ("k", "v")
+    if n_lead(cfg):
+        lead, caches = caches
+        cache = {**dict(zip(names, caches)), "lead": dict(zip(names, lead))}
     else:
-        cache = {"k": caches[0], "v": caches[1]}
+        cache = dict(zip(names, caches))
     cache_len = jnp.full((B,), S, jnp.int32)
     return logits[:, -1], cache, cache_len
 
@@ -308,59 +413,29 @@ def decode_step(
     cache_len: jax.Array,  # [B] current valid length (new token goes here)
     *,
     unroll: int = 1,
+    route_stats: bool = False,
+    moe_dispatch: str = "dropless",
 ):
-    """One decode step. Returns (logits [B,V], new_cache, new_cache_len)."""
-    B = tokens.shape[0]
+    """One decode step. Returns (logits [B,V], new_cache, new_cache_len), and
+    with ``route_stats`` the MoE layers' route counts [L_moe, held + 1]
+    (``moe_ffn_dropless``'s hits; None without MoE or with the "capacity"
+    ``moe_dispatch``) after them."""
     x = jnp.take(params["embed"], tokens, axis=0).astype(cfg.dtype)
     positions = cache_len[:, None]  # [B,1]
-    flags = jnp.asarray(layer_flags(cfg))
     window = None if cfg.local_global is None else cfg.local_window
 
     if cfg.mla is not None:
-        m = cfg.mla
 
         def body(carry, xs):
-            layer_p, c_kv_c, k_rope_c, is_global = xs
-            h = rms_norm(carry, layer_p["ln1"])
-            ckv_full = jnp.einsum("bsd,dr->bsr", h, layer_p["attn"]["w_dkv"])
-            new_ckv, new_krope = (
-                ckv_full[..., : -m.rope_head_dim],
-                ckv_full[..., -m.rope_head_dim :],
-            )
-            new_krope = apply_rope(new_krope[:, :, None, :], positions, cfg.rope_theta)[
-                :, :, 0, :
-            ]
-            c_kv_c = insert_kv(c_kv_c, new_ckv, cache_len)
-            k_rope_c = insert_kv(k_rope_c, new_krope, cache_len)
-            out = mla_decode(
-                h,
-                layer_p["attn"],
-                c_kv_c,
-                k_rope_c,
-                cache_len,
-                n_heads=cfg.n_heads,
-                nope=m.nope_head_dim,
-                rope=m.rope_head_dim,
-                v_dim=m.v_head_dim,
-                positions=positions,
-                theta=cfg.rope_theta,
-            )
-            out = jnp.einsum("bse,ed->bsd", out, layer_p["attn"]["w_o"])
-            y = carry + out
-            y = y + _ffn_block(cfg, layer_p, rms_norm(y, layer_p["ln2"]))
-            return y, (c_kv_c, k_rope_c)
+            layer_p, lc, is_global = xs
+            return _mla_step(cfg, layer_p, carry, lc, cache_len, positions, mla_decode,
+                             moe_dispatch)
 
-        x, (c_kv_new, k_rope_new) = jax.lax.scan(
-            body,
-            x,
-            (params["layers"], cache["c_kv"], cache["k_rope"], flags),
-            unroll=unroll,
-        )
-        new_cache = {"c_kv": c_kv_new, "k_rope": k_rope_new}
     else:
 
         def body(carry, xs):
-            layer_p, k_c, v_c, is_global = xs
+            layer_p, lc, is_global = xs
+            k_c, v_c = lc["k"], lc["v"]
             ap = layer_p["attn"]
             h = rms_norm(carry, layer_p["ln1"])
             q = jnp.einsum("bsd,dhe->bshe", h, ap["wq"])
@@ -375,36 +450,35 @@ def decode_step(
             )
             out = jnp.einsum("bshe,hed->bsd", out, ap["wo"])
             y = carry + out
-            y = y + _ffn_block(cfg, layer_p, rms_norm(y, layer_p["ln2"]))
-            return y, (k_c, v_c)
+            f, hits = _ffn_block(cfg, layer_p, rms_norm(y, layer_p["ln2"]), moe_dispatch)
+            return y + f, ({"k": k_c, "v": v_c}, hits)
 
-        x, (k_new, v_new) = jax.lax.scan(
-            body, x, (params["layers"], cache["k"], cache["v"], flags), unroll=unroll
-        )
-        new_cache = {"k": k_new, "v": v_new}
-
+    x, new_cache, hits = _scan_steps(cfg, params, cache, body, x, unroll)
     x = rms_norm(x, params["final_norm"])
-    head = params.get("head")
-    if head is None:
-        logits = jnp.einsum("bsd,vd->bsv", x, params["embed"])
-    else:
-        logits = jnp.einsum("bsd,dv->bsv", x, head)
-    return logits[:, 0].astype(jnp.float32), new_cache, cache_len + 1
+    logits = _logits(params, x)
+    out = (logits[:, 0].astype(jnp.float32), new_cache, cache_len + 1)
+    return (*out, hits) if route_stats else out
 
 
 def init_cache(cfg: LMConfig, batch: int, max_len: int) -> dict:
-    """Zeroed KV (or MLA latent) cache stacked over layers."""
-    L = cfg.n_layers
-    if cfg.mla is not None:
-        m = cfg.mla
-        return {
-            "c_kv": jnp.zeros((L, batch, max_len, m.kv_lora_rank), cfg.dtype),
-            "k_rope": jnp.zeros((L, batch, max_len, m.rope_head_dim), cfg.dtype),
-        }
-    return {
-        "k": jnp.zeros((L, batch, max_len, cfg.n_kv_heads, cfg.head_dim), cfg.dtype),
-        "v": jnp.zeros((L, batch, max_len, cfg.n_kv_heads, cfg.head_dim), cfg.dtype),
-    }
+    """Zeroed KV (or MLA latent) cache stacked over layers; the leading dense
+    layers' under ``"lead"``."""
+
+    def zeros(L):
+        if cfg.mla is not None:
+            m = cfg.mla
+            return {
+                "c_kv": jnp.zeros((L, batch, max_len, m.kv_lora_rank), cfg.dtype),
+                "k_rope": jnp.zeros((L, batch, max_len, m.rope_head_dim), cfg.dtype),
+            }
+        shape = (L, batch, max_len, cfg.n_kv_heads, cfg.head_dim)
+        return {"k": jnp.zeros(shape, cfg.dtype), "v": jnp.zeros(shape, cfg.dtype)}
+
+    k = n_lead(cfg)
+    cache = zeros(cfg.n_layers - k)
+    if k:
+        cache["lead"] = zeros(k)
+    return cache
 
 
 def prefill_chunk(
@@ -415,49 +489,33 @@ def prefill_chunk(
     cache_len: jax.Array,  # [B] valid length before this chunk
     *,
     unroll: int = 1,
+    route_stats: bool = False,
 ):
     """Chunked prefill against an existing cache (serving engine / RISP
     prefix reuse): appends c tokens at positions cache_len..cache_len+c-1.
-    Returns (last-position logits [B,V], new_cache, new_cache_len)."""
+    Returns (last-position logits [B,V], new_cache, new_cache_len), and with
+    ``route_stats`` the route counts as ``decode_step`` gives them.
+
+    MLA attends in the decompressed form, blocked over the live keys
+    (``mla_chunk_attend``): a chunk's queries are many, so decompressing its
+    keys costs less than absorbing W_uk into every query."""
     B, c = tokens.shape
     x = jnp.take(params["embed"], tokens, axis=0).astype(cfg.dtype)
     positions = cache_len[:, None] + jnp.arange(c)[None, :]
-    flags = jnp.asarray(layer_flags(cfg))
     window = None if cfg.local_global is None else cfg.local_window
 
     if cfg.mla is not None:
-        m = cfg.mla
 
         def body(carry, xs):
-            layer_p, c_kv_c, k_rope_c, is_global = xs
-            h = rms_norm(carry, layer_p["ln1"])
-            ckv_full = jnp.einsum("bsd,dr->bsr", h, layer_p["attn"]["w_dkv"])
-            new_ckv = ckv_full[..., : -m.rope_head_dim]
-            new_krope = apply_rope(
-                ckv_full[..., -m.rope_head_dim :][:, :, None, :], positions,
-                cfg.rope_theta,
-            )[:, :, 0, :]
-            c_kv_c = insert_chunk(c_kv_c, new_ckv, cache_len)
-            k_rope_c = insert_chunk(k_rope_c, new_krope, cache_len)
-            out = mla_decode(
-                h, layer_p["attn"], c_kv_c, k_rope_c, cache_len,
-                n_heads=cfg.n_heads, nope=m.nope_head_dim, rope=m.rope_head_dim,
-                v_dim=m.v_head_dim, positions=positions, theta=cfg.rope_theta,
-            )
-            out = jnp.einsum("bse,ed->bsd", out, layer_p["attn"]["w_o"])
-            y = carry + out
-            y = y + _ffn_block(cfg, layer_p, rms_norm(y, layer_p["ln2"]))
-            return y, (c_kv_c, k_rope_c)
+            layer_p, lc, is_global = xs
+            return _mla_step(cfg, layer_p, carry, lc, cache_len, positions,
+                             mla_chunk_attend)
 
-        x, (ckv_new, krope_new) = jax.lax.scan(
-            body, x, (params["layers"], cache["c_kv"], cache["k_rope"], flags),
-            unroll=unroll,
-        )
-        new_cache = {"c_kv": ckv_new, "k_rope": krope_new}
     else:
 
         def body(carry, xs):
-            layer_p, k_c, v_c, is_global = xs
+            layer_p, lc, is_global = xs
+            k_c, v_c = lc["k"], lc["v"]
             ap = layer_p["attn"]
             h = rms_norm(carry, layer_p["ln1"])
             q = jnp.einsum("bsd,dhe->bshe", h, ap["wq"])
@@ -472,18 +530,11 @@ def prefill_chunk(
             )
             out = jnp.einsum("bshe,hed->bsd", out, ap["wo"])
             y = carry + out
-            y = y + _ffn_block(cfg, layer_p, rms_norm(y, layer_p["ln2"]))
-            return y, (k_c, v_c)
+            f, hits = _ffn_block(cfg, layer_p, rms_norm(y, layer_p["ln2"]))
+            return y + f, ({"k": k_c, "v": v_c}, hits)
 
-        x, (k_new, v_new) = jax.lax.scan(
-            body, x, (params["layers"], cache["k"], cache["v"], flags), unroll=unroll
-        )
-        new_cache = {"k": k_new, "v": v_new}
-
+    x, new_cache, hits = _scan_steps(cfg, params, cache, body, x, unroll)
     x = rms_norm(x, params["final_norm"])
-    head = params.get("head")
-    if head is None:
-        logits = jnp.einsum("bsd,vd->bsv", x, params["embed"])
-    else:
-        logits = jnp.einsum("bsd,dv->bsv", x, head)
-    return logits[:, -1].astype(jnp.float32), new_cache, cache_len + c
+    logits = _logits(params, x)
+    out = (logits[:, -1].astype(jnp.float32), new_cache, cache_len + c)
+    return (*out, hits) if route_stats else out

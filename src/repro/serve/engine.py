@@ -93,6 +93,14 @@ class ServeMetrics:
             "repro_serve_prefill_saved_seconds_total",
             "prefill seconds avoided by snapshot reuse (measured cost minus load)",
         )
+        self.expert_assignments = m.counter(
+            "repro_serve_expert_assignments_total",
+            "token-to-expert assignments routed (top-k per token per MoE layer)",
+        )
+        self.expert_held = m.counter(
+            "repro_serve_expert_held_assignments_total",
+            "routed assignments that went to the experts this engine holds",
+        )
 
 
 @dataclass
@@ -106,6 +114,7 @@ class _PrefixResult:
     skipped: int  # chunks restored from a snapshot instead of prefilled
     stored: int  # snapshot admissions performed
     prefill_s: float  # wall seconds of prefill done here
+    routed: Any = None  # route totals of the steps run here (MoE), on the device
 
 
 @dataclass
@@ -162,14 +171,46 @@ class ServeEngine:
         cfg = self.cfg
 
         # named, so a profiler shows ``jit_prefill_chunk`` / ``jit_decode_step``
-        def prefill_chunk(p, t, c, l):
-            return transformer.prefill_chunk(p, cfg, t, c, l)
+        if cfg.moe is None:
 
-        def decode_step(p, t, c, l):
-            return transformer.decode_step(p, cfg, t, c, l)
+            def prefill_chunk(p, t, c, l):
+                return transformer.prefill_chunk(p, cfg, t, c, l)
+
+            def decode_step(p, t, c, l):
+                return transformer.decode_step(p, cfg, t, c, l)
+
+        else:
+            # MoE: each step also adds its route totals [assignments, held]
+            # to the request's running pair on the device, ``n``, and hands
+            # back its per-layer counts; the pair is read once per request
+            held = cfg.moe.held
+
+            def totals(n, hits):
+                return n + jnp.stack([hits.sum(), hits[:, :held].sum()])
+
+            def prefill_chunk(p, t, c, l, n):
+                logits, c, l, hits = transformer.prefill_chunk(
+                    p, cfg, t, c, l, route_stats=True)
+                return logits, c, l, totals(n, hits), hits
+
+            def decode_step(p, t, c, l, n):
+                logits, c, l, hits = transformer.decode_step(
+                    p, cfg, t, c, l, route_stats=True)
+                return logits, c, l, totals(n, hits), hits
 
         self._prefill = jax.jit(prefill_chunk)
         self._decode = jax.jit(decode_step)
+
+    def _new_routed(self):
+        """A request's route totals [assignments, held]: None without MoE."""
+        return None if self.cfg.moe is None else jnp.zeros(2, jnp.int32)
+
+    def _step(self, fn, tok, cache, cache_len, routed):
+        """One call of a step program: (logits, cache, cache_len, routed)."""
+        if routed is None:
+            return (*fn(self.params, tok, cache, cache_len), None)
+        logits, cache, cache_len, routed, _ = fn(self.params, tok, cache, cache_len, routed)
+        return logits, cache, cache_len, routed
 
     # -- RISP bookkeeping over request chunks ------------------------------
     def _workflow(self, chunks: list[np.ndarray]) -> Workflow:
@@ -249,6 +290,7 @@ class ServeEngine:
         t0 = time.perf_counter()
         cache_len = jnp.asarray([cache_len_i], jnp.int32)
         logits = None
+        routed = self._new_routed()
         boundary: dict[int, tuple[Any, int]] = {}
         # measured recompute cost of each boundary's prefix: seconds actually
         # spent this request, plus the EMA-priced skipped part — this is what
@@ -259,7 +301,8 @@ class ServeEngine:
         for i in range(start, upto):
             tok = jnp.asarray(chunks[i][None], jnp.int32)
             tc = time.perf_counter()
-            logits, cache, cache_len = self._prefill(self.params, tok, cache, cache_len)
+            logits, cache, cache_len, routed = self._step(
+                self._prefill, tok, cache, cache_len, routed)
             jax.block_until_ready(logits)
             dt = time.perf_counter() - tc
             self._chunk_prefill_s = (
@@ -301,6 +344,7 @@ class ServeEngine:
             skipped=start,
             stored=stored,
             prefill_s=prefill_s,
+            routed=routed,
         )
 
     # -- generation ---------------------------------------------------------
@@ -345,19 +389,20 @@ class ServeEngine:
                         skipped=value.depth,
                         stored=0,
                         prefill_s=0.0,
+                        routed=self._new_routed(),  # the leader counted its steps
                     )
             else:
                 value = self._prefill_prefix(chunks, wf, rec, depth_keys, upto=n)
 
             # uncoordinated remainder: this request's private suffix
             cache, cache_len = value.cache, value.cache_len
-            logits = value.logits
+            logits, routed = value.logits, value.routed
             t_ext = time.perf_counter()
             for i in range(value.depth, n):
                 tok = jnp.asarray(chunks[i][None], jnp.int32)
                 tc = time.perf_counter()
-                logits, cache, cache_len = self._prefill(
-                    self.params, tok, cache, cache_len
+                logits, cache, cache_len, routed = self._step(
+                    self._prefill, tok, cache, cache_len, routed
                 )
                 jax.block_until_ready(logits)
                 dt = time.perf_counter() - tc
@@ -377,8 +422,8 @@ class ServeEngine:
             if logits is None:  # full-prompt cache hit: re-run last chunk's logits
                 tok = jnp.asarray(chunks[-1][None], jnp.int32)
                 trimmed_cache, trimmed_len = self._trim_last_chunk(cache, cache_len)
-                logits, cache, cache_len = self._prefill(
-                    self.params, tok, trimmed_cache, trimmed_len
+                logits, cache, cache_len, routed = self._step(
+                    self._prefill, tok, trimmed_cache, trimmed_len, routed
                 )
             for _ in range(max_new_tokens):
                 nxt = int(jnp.argmax(logits[0]))
@@ -386,9 +431,11 @@ class ServeEngine:
                     first_token_s = time.perf_counter() - t_in
                 out.append(nxt)
                 tok = jnp.asarray([[nxt]], jnp.int32)
-                logits, cache, cache_len = self._decode(
-                    self.params, tok, cache, cache_len
+                logits, cache, cache_len, routed = self._step(
+                    self._decode, tok, cache, cache_len, routed
                 )
+            if routed is not None:
+                routed = np.asarray(routed)  # read once, after the last step
         decode_s = time.perf_counter() - t1
 
         stats = GenStats(
@@ -414,6 +461,9 @@ class ServeEngine:
         m.tokens.inc(stats.n_new_tokens)
         m.stored.inc(stats.stored_prefixes)
         m.busy.inc(stats.prefill_s + stats.decode_s)
+        if routed is not None:
+            m.expert_assignments.inc(int(routed[0]))
+            m.expert_held.inc(int(routed[1]))
         return out, stats
 
     def _trim_last_chunk(self, cache, cache_len):

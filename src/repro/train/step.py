@@ -54,7 +54,8 @@ def build_param_specs(cfg: Config, cell: ShapeCell | None = None) -> Any:
 def _lm_loss(params, cfg: LMConfig, batch, *, remat=None, unroll=1):
     if cfg.loss_vocab_chunks:
         x, _ = transformer.forward(
-            params, cfg, batch["tokens"], remat=remat, unroll=unroll, no_head=True
+            params, cfg, batch["tokens"], remat=remat, unroll=unroll, no_head=True,
+            moe_dispatch="capacity",
         )
         head = params.get("head")
         if head is None:
@@ -64,7 +65,8 @@ def _lm_loss(params, cfg: LMConfig, batch, *, remat=None, unroll=1):
         )
         return loss, {"loss": loss, "ppl_proxy": loss}
     logits, _ = transformer.forward(
-        params, cfg, batch["tokens"], remat=remat, unroll=unroll
+        params, cfg, batch["tokens"], remat=remat, unroll=unroll,
+        moe_dispatch="capacity",  # training keeps the GShard dispatch
     )
     logp = jax.nn.log_softmax(logits, axis=-1)
     ce = -jnp.take_along_axis(logp, batch["targets"][..., None], axis=-1)[..., 0]
@@ -202,18 +204,24 @@ def build_train_step(
 # serve step
 # --------------------------------------------------------------------------
 def build_serve_step(cfg: Config, cell: ShapeCell, *, unroll: int = 1) -> Callable:
+    # the dry-run's serving cells keep the GShard dispatch, whose einsums
+    # GSPMD shards over the experts axis; the dropless dispatch's grouped
+    # matmuls carry no expert-parallel layout yet (PERF.md)
     if isinstance(cfg, LMConfig):
         if cell.kind == "prefill":
 
             def prefill_step(params, tokens):
-                return transformer.prefill(params, cfg, tokens, unroll=unroll)
+                return transformer.prefill(
+                    params, cfg, tokens, unroll=unroll, moe_dispatch="capacity"
+                )
 
             return prefill_step
         if cell.kind == "decode":
 
             def decode_step(params, tokens, cache, cache_len):
                 return transformer.decode_step(
-                    params, cfg, tokens, cache, cache_len, unroll=unroll
+                    params, cfg, tokens, cache, cache_len, unroll=unroll,
+                    moe_dispatch="capacity",
                 )
 
             return decode_step

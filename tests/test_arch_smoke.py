@@ -101,9 +101,6 @@ def test_lm_decode_matches_prefill(arch):
     # fp32: this validates path equivalence (absorbed-MLA decode, gemma3 local
     # masks, cache insertion), not bf16 rounding between contraction orders
     cfg = dataclasses.replace(cfg, dtype=jnp.float32)
-    if cfg.moe is not None:
-        # decode batches route without drops; match by lifting train capacity
-        cfg = dataclasses.replace(cfg, moe_capacity_factor=16.0)
     from repro.models import transformer
 
     B, S = 2, 12

@@ -84,12 +84,14 @@ def test_moe_ep_annotations_preserve_values():
     tokens = jnp.asarray(rng.integers(0, cfg.vocab, (2, 32)), jnp.int32)
     from repro.models import transformer
 
-    ref, _ = transformer.forward(params, cfg, tokens)
+    # the annotations live in the capacity (training) dispatch
+    ref, _ = transformer.forward(params, cfg, tokens, moe_dispatch="capacity")
     # single-device mesh: annotations must be value-neutral
     mesh = jax.make_mesh(
         (1, 1), ("data", "model"), axis_types=(jax.sharding.AxisType.Auto,) * 2
     )
     cfg_a = dataclasses.replace(cfg, moe_ep_axis="model", moe_token_axes=("data",))
     with jax.set_mesh(mesh):
-        out, _ = jax.jit(lambda p, t: transformer.forward(p, cfg_a, t))(params, tokens)
+        out, _ = jax.jit(lambda p, t: transformer.forward(
+            p, cfg_a, t, moe_dispatch="capacity"))(params, tokens)
     np.testing.assert_allclose(np.asarray(ref), np.asarray(out), atol=1e-5)
