@@ -9,6 +9,7 @@ from .decode_attention import decode_attention, decode_attention_ref
 from .embedding_bag import embedding_bag, embedding_bag_ref
 from .flash_attention import attention_ref, flash_attention
 from .gnn_aggregate import edge_to_padded, gnn_aggregate, gnn_aggregate_ref
+from .mla_chunk_attention import mla_chunk_attention, mla_chunk_attention_ref
 
 __all__ = [
     "attention_ref",
@@ -20,4 +21,6 @@ __all__ = [
     "flash_attention",
     "gnn_aggregate",
     "gnn_aggregate_ref",
+    "mla_chunk_attention",
+    "mla_chunk_attention_ref",
 ]

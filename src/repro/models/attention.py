@@ -1,14 +1,21 @@
-"""Attention variants (XLA path): GQA full/causal/local, MLA, cached decode.
+"""Attention variants: GQA full/causal/local, MLA, cached decode.
 
-These pure-jnp implementations are the default lowering path (and the oracle
-for the Pallas kernels in ``repro.kernels``).  Models switch to the Pallas
-flash kernels on TPU via ``attention_impl="pallas"``.
+These are pure jnp, with one exception on a served path: a prefill chunk's
+cached latent attention (``mla_chunk_attend``, DeepSeek-V2 through
+``ServeEngine``) lowers to the Pallas kernel
+``repro.kernels.mla_chunk_attention`` when it is lowered for a TPU, and to
+its XLA form for any other platform (``jax.lax.platform_dependent``; no
+option chooses).  The other kernels in ``repro.kernels`` are on no served
+path; the jnp forms here are their oracles.
 """
 from __future__ import annotations
+
+from functools import partial
 
 import jax
 import jax.numpy as jnp
 
+from ..kernels.mla_chunk_attention import mla_chunk_attention
 from .layers import apply_rope_interleaved, rms_norm, yarn_mscale
 
 NEG_INF = -1e30
@@ -263,15 +270,30 @@ def mla_chunk_attend(
     scale: float,
     block: int = 512,
 ) -> jax.Array:
-    """Decompressed cached attention for a prefill chunk, over key blocks of
-    ``block`` with an online softmax.  Only the blocks up to the live length
-    (q_start + c) are visited, so the work and the peak memory (one float32
-    [B,H,c,block] score tile) do not grow with the cache's ``max_len``.
-    Returns [B,c,H*v_dim]."""
+    """Decompressed cached attention for a prefill chunk: query s attends
+    keys 0..q_start+s.  Lowered for a TPU it is one Pallas kernel
+    (``repro.kernels.mla_chunk_attention``: score tiles stay in VMEM); for
+    any other platform it is ``_mla_chunk_blocks``, over key blocks of
+    ``block`` (the kernel keeps its own tiling, so ``block`` applies only
+    off the TPU).  Returns [B,c,H*v_dim]."""
+    with jax.named_scope("mla.attend"):
+        return jax.lax.platform_dependent(
+            q, p["w_uk"], p["w_uv"], c_kv_cache, k_rope_cache, q_start,
+            tpu=partial(mla_chunk_attention, scale=scale),
+            default=partial(_mla_chunk_blocks, scale=scale, block=block),
+        )
+
+
+def _mla_chunk_blocks(q, w_uk, w_uv, c_kv_cache, k_rope_cache, q_start, *,
+                      scale: float, block: int) -> jax.Array:
+    """``mla_chunk_attend`` in XLA, over key blocks of ``block`` with an
+    online softmax.  Only the blocks up to the live length (q_start + c) are
+    visited, so the work and the peak memory (one float32 [B,H,c,block]
+    score tile) do not grow with the cache's ``max_len``."""
     B, c, H, _ = q.shape
     T = c_kv_cache.shape[1]
-    nope = p["w_uk"].shape[-1]
-    v_dim = p["w_uv"].shape[-1]
+    nope = w_uk.shape[-1]
+    v_dim = w_uv.shape[-1]
     blk = block if T % block == 0 else T
     qpos = q_start[:, None] + jnp.arange(c)[None, :]  # [B,c]
     n_blocks = (jnp.max(q_start) + c + blk - 1) // blk
@@ -280,8 +302,8 @@ def mla_chunk_attend(
         m_prev, l_prev, acc = carry
         ckv = jax.lax.dynamic_slice_in_dim(c_kv_cache, i * blk, blk, axis=1)
         krope = jax.lax.dynamic_slice_in_dim(k_rope_cache, i * blk, blk, axis=1)
-        k_nope = jnp.einsum("btr,rhe->bthe", ckv, p["w_uk"])
-        v = jnp.einsum("btr,rhe->bthe", ckv, p["w_uv"])
+        k_nope = jnp.einsum("btr,rhe->bthe", ckv, w_uk)
+        v = jnp.einsum("btr,rhe->bthe", ckv, w_uv)
         s = jnp.einsum("bshe,bthe->bhst", q[..., :nope], k_nope)
         s = s + jnp.einsum("bshe,bte->bhst", q[..., nope:], krope)
         s = s.astype(jnp.float32) * scale
@@ -301,9 +323,8 @@ def mla_chunk_attend(
         jnp.zeros((B, H, c), jnp.float32),
         jnp.zeros((B, c, H, v_dim), jnp.float32),
     )
-    with jax.named_scope("mla.attend"):
-        _, l_fin, acc = jax.lax.fori_loop(0, n_blocks, body, init)
-        out = acc / jnp.moveaxis(l_fin, 1, 2)[..., None]
+    _, l_fin, acc = jax.lax.fori_loop(0, n_blocks, body, init)
+    out = acc / jnp.moveaxis(l_fin, 1, 2)[..., None]
     return out.astype(q.dtype).reshape(B, c, H * v_dim)
 
 
