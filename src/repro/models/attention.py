@@ -1,4 +1,11 @@
-"""Attention variants: GQA full/causal/local, MLA, cached decode.
+"""Attention variants: GQA full/causal/local, MLA, cached steps.
+
+A cached step's new rows reach the cache through one writer, ``put_rows``
+(a dynamic-update-slice per batch row, overwriting).  Latent attention
+writes a layer's rows before it attends them; GQA attends the cache as it
+was plus the step's own rows (``decode_attention(new=...)``), and the step
+writes every layer's rows once, after its layer scan, so the scan copies
+no layer's cache.
 
 These are pure jnp, with one exception on a served path: a prefill chunk's
 cached latent attention (``mla_chunk_attend``, DeepSeek-V2 through
@@ -68,32 +75,47 @@ def gqa_attention(
 
 def decode_attention(
     q: jax.Array,  # [B,S,Hq,dh]  (S=1 decode; S=chunk for chunked prefill)
-    k_cache: jax.Array,  # [B,T,Hkv,dh]  (new kv already inserted)
+    k_cache: jax.Array,  # [B,T,Hkv,dh]
     v_cache: jax.Array,
     q_start: jax.Array,  # [B] position of the FIRST query token
     *,
     window: int | None = None,
     global_flag: jax.Array | None = None,
+    new: tuple[jax.Array, jax.Array] | None = None,
 ) -> jax.Array:
-    """Cached attention: query token s attends kpos <= q_start+s."""
+    """Cached attention: query token s attends kpos <= q_start+s.
+
+    Without ``new`` the queries' own keys are already in the cache.  With
+    ``new`` = (k, v) [B,S,Hkv,dh], the queries' own keys, not yet written:
+    the cache's rows from q_start on are ignored, and query s attends the
+    rows before q_start and new rows 0..s.  The cache is then only read, so
+    a step can write its rows once, after its last layer."""
     B, S, Hq, dh = q.shape
     _, T, Hkv, _ = k_cache.shape
     G = Hq // Hkv
     qg = q.reshape(B, S, Hkv, G, dh)
-    scores = jnp.einsum("bskgd,btkd->bkgst", qg, k_cache).astype(jnp.float32)
-    scores = scores / jnp.sqrt(dh).astype(jnp.float32)
-    kpos = jnp.arange(T)
+    scores = jnp.einsum("bskgd,btkd->bkgst", qg, k_cache)
+    kpos = jnp.broadcast_to(jnp.arange(T), (B, T))
+    if new is not None:
+        live = jnp.where(kpos < q_start[:, None], kpos, jnp.iinfo(jnp.int32).max)
+        kpos = jnp.concatenate([live, q_start[:, None] + jnp.arange(S)], axis=1)
+        own = jnp.einsum("bskgd,btkd->bkgst", qg, new[0].astype(k_cache.dtype))
+        scores = jnp.concatenate([scores, own], axis=-1)
+    scores = scores.astype(jnp.float32) / jnp.sqrt(dh).astype(jnp.float32)
     qpos = q_start[:, None] + jnp.arange(S)[None, :]  # [B,S]
-    mask = kpos[None, None, :] <= qpos[:, :, None]  # [B,S,T]
+    mask = kpos[:, None, :] <= qpos[:, :, None]  # [B,S,keys]
     if window is not None:
-        in_window = kpos[None, None, :] > qpos[:, :, None] - window
+        in_window = kpos[:, None, :] > qpos[:, :, None] - window
         if global_flag is not None:
             in_window = in_window | (global_flag > 0.5)
         mask &= in_window
     scores = jnp.where(mask[:, None, None, :, :], scores, NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-    out = jnp.einsum("bkgst,btkd->bskgd", probs, v_cache)
-    return out.reshape(B, S, Hq, v_cache.shape[-1])
+    pv = partial(jnp.einsum, "bkgst,btkd->bskgd", preferred_element_type=jnp.float32)
+    out = pv(probs[..., :T], v_cache)
+    if new is not None:
+        out = out + pv(probs[..., T:], new[1].astype(v_cache.dtype))
+    return out.astype(q.dtype).reshape(B, S, Hq, v_cache.shape[-1])
 
 
 def chunked_gqa_attention(
@@ -142,17 +164,6 @@ def chunked_gqa_attention(
     v_dim = outs.shape[-1]  # may differ from q's head dim (MLA)
     out = jnp.moveaxis(outs, 0, 1).reshape(B, nb * block_q, Hq, v_dim)
     return out[:, :S]
-
-
-def insert_chunk(cache: jax.Array, new: jax.Array, cache_len: jax.Array) -> jax.Array:
-    """Scatter new [B,c,...] into cache [B,T,...] at positions cache_len+j."""
-    B, T = cache.shape[:2]
-    c = new.shape[1]
-    kpos = jnp.arange(T)
-    oh = (
-        kpos[None, :, None] == (cache_len[:, None, None] + jnp.arange(c)[None, None, :])
-    ).astype(cache.dtype)
-    return cache + jnp.einsum("btc,bc...->bt...", oh, new)
 
 
 # --------------------------------------------------------------------------
@@ -329,15 +340,11 @@ def _mla_chunk_blocks(q, w_uk, w_uv, c_kv_cache, k_rope_cache, q_start, *,
 
 
 def put_rows(cache: jax.Array, new: jax.Array, cache_len: jax.Array) -> jax.Array:
-    """Write new [B,c,...] into cache [B,T,...] at rows cache_len..+c-1."""
+    """Write new [B,c,...] into cache [B,T,...] at rows cache_len..+c-1,
+    overwriting what was there.  A start past T - c is clamped to T - c, so
+    callers keep cache_len + c <= T (``ServeEngine.generate`` refuses a
+    request that would not)."""
     return jax.vmap(
         lambda a, n, i: jax.lax.dynamic_update_slice_in_dim(a, n, i, axis=0)
     )(cache, new.astype(cache.dtype), cache_len)
 
-
-def insert_kv(cache: jax.Array, new: jax.Array, cache_len: jax.Array) -> jax.Array:
-    """Scatter new [B,1,...] into cache [B,T,...] at position cache_len[B]."""
-    T = cache.shape[1]
-    onehot = (jnp.arange(T)[None] == cache_len[:, None]).astype(cache.dtype)
-    shape = (cache.shape[0], T) + (1,) * (cache.ndim - 2)
-    return cache + onehot.reshape(shape) * new

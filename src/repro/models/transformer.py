@@ -18,8 +18,6 @@ from .attention import (
     chunked_gqa_attention,
     decode_attention,
     gqa_attention,
-    insert_chunk,
-    insert_kv,
     mla_chunk_attend,
     mla_decode,
     mla_prefill,
@@ -405,61 +403,6 @@ def prefill(params: dict, cfg: LMConfig, tokens: jax.Array, *, unroll: int = 1,
     return logits[:, -1], cache, cache_len
 
 
-def decode_step(
-    params: dict,
-    cfg: LMConfig,
-    tokens: jax.Array,  # [B,1]
-    cache: dict,  # stacked over layers: [L,B,T,...]
-    cache_len: jax.Array,  # [B] current valid length (new token goes here)
-    *,
-    unroll: int = 1,
-    route_stats: bool = False,
-    moe_dispatch: str = "dropless",
-):
-    """One decode step. Returns (logits [B,V], new_cache, new_cache_len), and
-    with ``route_stats`` the MoE layers' route counts [L_moe, held + 1]
-    (``moe_ffn_dropless``'s hits; None without MoE or with the "capacity"
-    ``moe_dispatch``) after them."""
-    x = jnp.take(params["embed"], tokens, axis=0).astype(cfg.dtype)
-    positions = cache_len[:, None]  # [B,1]
-    window = None if cfg.local_global is None else cfg.local_window
-
-    if cfg.mla is not None:
-
-        def body(carry, xs):
-            layer_p, lc, is_global = xs
-            return _mla_step(cfg, layer_p, carry, lc, cache_len, positions, mla_decode,
-                             moe_dispatch)
-
-    else:
-
-        def body(carry, xs):
-            layer_p, lc, is_global = xs
-            k_c, v_c = lc["k"], lc["v"]
-            ap = layer_p["attn"]
-            h = rms_norm(carry, layer_p["ln1"])
-            q = jnp.einsum("bsd,dhe->bshe", h, ap["wq"])
-            k = jnp.einsum("bsd,dhe->bshe", h, ap["wk"])
-            v = jnp.einsum("bsd,dhe->bshe", h, ap["wv"])
-            q = apply_rope(q, positions, cfg.rope_theta)
-            k = apply_rope(k, positions, cfg.rope_theta)
-            k_c = insert_kv(k_c, k, cache_len)
-            v_c = insert_kv(v_c, v, cache_len)
-            out = decode_attention(
-                q, k_c, v_c, cache_len, window=window, global_flag=is_global
-            )
-            out = jnp.einsum("bshe,hed->bsd", out, ap["wo"])
-            y = carry + out
-            f, hits = _ffn_block(cfg, layer_p, rms_norm(y, layer_p["ln2"]), moe_dispatch)
-            return y + f, ({"k": k_c, "v": v_c}, hits)
-
-    x, new_cache, hits = _scan_steps(cfg, params, cache, body, x, unroll)
-    x = rms_norm(x, params["final_norm"])
-    logits = _logits(params, x)
-    out = (logits[:, 0].astype(jnp.float32), new_cache, cache_len + 1)
-    return (*out, hits) if route_stats else out
-
-
 def init_cache(cfg: LMConfig, batch: int, max_len: int) -> dict:
     """Zeroed KV (or MLA latent) cache stacked over layers; the leading dense
     layers' under ``"lead"``."""
@@ -490,13 +433,17 @@ def prefill_chunk(
     *,
     unroll: int = 1,
     route_stats: bool = False,
+    moe_dispatch: str = "dropless",
 ):
-    """Chunked prefill against an existing cache (serving engine / RISP
-    prefix reuse): appends c tokens at positions cache_len..cache_len+c-1.
-    Returns (last-position logits [B,V], new_cache, new_cache_len), and with
-    ``route_stats`` the route counts as ``decode_step`` gives them.
+    """The one cached step, for any width c >= 1 (serving engine / RISP
+    prefix reuse): attends each of c tokens to the rows before it and
+    writes them at rows cache_len..cache_len+c-1.  Returns (last-position logits [B,V],
+    new_cache, cache_len + c), and with ``route_stats`` the MoE layers'
+    route counts [L_moe, held + 1] (``moe_ffn_dropless``'s hits; None
+    without MoE or with the "capacity" ``moe_dispatch``) after them.
 
-    MLA attends in the decompressed form, blocked over the live keys
+    MLA attends a single token in the absorbed form (``mla_decode``) and a
+    wider chunk in the decompressed form, blocked over the live keys
     (``mla_chunk_attend``): a chunk's queries are many, so decompressing its
     keys costs less than absorbing W_uk into every query."""
     B, c = tokens.shape
@@ -505,17 +452,17 @@ def prefill_chunk(
     window = None if cfg.local_global is None else cfg.local_window
 
     if cfg.mla is not None:
+        attend = mla_decode if c == 1 else mla_chunk_attend
 
         def body(carry, xs):
             layer_p, lc, is_global = xs
-            return _mla_step(cfg, layer_p, carry, lc, cache_len, positions,
-                             mla_chunk_attend)
+            return _mla_step(cfg, layer_p, carry, lc, cache_len, positions, attend,
+                             moe_dispatch)
 
     else:
 
         def body(carry, xs):
             layer_p, lc, is_global = xs
-            k_c, v_c = lc["k"], lc["v"]
             ap = layer_p["attn"]
             h = rms_norm(carry, layer_p["ln1"])
             q = jnp.einsum("bsd,dhe->bshe", h, ap["wq"])
@@ -523,18 +470,39 @@ def prefill_chunk(
             v = jnp.einsum("bsd,dhe->bshe", h, ap["wv"])
             q = apply_rope(q, positions, cfg.rope_theta)
             k = apply_rope(k, positions, cfg.rope_theta)
-            k_c = insert_chunk(k_c, k, cache_len)
-            v_c = insert_chunk(v_c, v, cache_len)
             out = decode_attention(
-                q, k_c, v_c, cache_len, window=window, global_flag=is_global
+                q, lc["k"], lc["v"], cache_len, window=window, global_flag=is_global,
+                new=(k, v),
             )
             out = jnp.einsum("bshe,hed->bsd", out, ap["wo"])
             y = carry + out
-            f, hits = _ffn_block(cfg, layer_p, rms_norm(y, layer_p["ln2"]))
-            return y + f, ({"k": k_c, "v": v_c}, hits)
+            f, hits = _ffn_block(cfg, layer_p, rms_norm(y, layer_p["ln2"]), moe_dispatch)
+            return y + f, ({"k": k, "v": v}, hits)
 
     x, new_cache, hits = _scan_steps(cfg, params, cache, body, x, unroll)
+    if cfg.mla is None:
+        # GQA's scan gave each layer's new rows; written here, not inside
+        # the scan, no layer's cache is copied out of it and back
+        write = jax.vmap(put_rows, in_axes=(0, 0, None))
+        new_cache = jax.tree_util.tree_map(
+            lambda a, rows: write(a, rows, cache_len), cache, new_cache)
     x = rms_norm(x, params["final_norm"])
     logits = _logits(params, x)
     out = (logits[:, -1].astype(jnp.float32), new_cache, cache_len + c)
     return (*out, hits) if route_stats else out
+
+
+def decode_step(
+    params: dict,
+    cfg: LMConfig,
+    tokens: jax.Array,  # [B,1]
+    cache: dict,
+    cache_len: jax.Array,  # [B] current valid length (new token goes here)
+    *,
+    unroll: int = 1,
+    route_stats: bool = False,
+    moe_dispatch: str = "dropless",
+):
+    """One decode step: ``prefill_chunk`` at width 1."""
+    return prefill_chunk(params, cfg, tokens, cache, cache_len, unroll=unroll,
+                         route_stats=route_stats, moe_dispatch=moe_dispatch)

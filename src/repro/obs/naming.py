@@ -68,7 +68,7 @@ ALIASES: dict[str, str] = {
     "serve:units_total": "repro_serve_chunks_total",
     "serve:units_skipped": "repro_serve_chunks_skipped_total",
     "serve:stored": "repro_serve_snapshots_stored_total",
-    # snapshot-store accounting (SnapshotStore attribute aliases)
+    # snapshot-store accounting (FabricSnapshotStore attribute aliases)
     "serve:n_snapshots": "repro_serve_snapshots",
     "serve:snapshot_bytes": "repro_serve_snapshot_stored_bytes",
     "serve:n_snapshot_evictions": "repro_serve_snapshot_evictions_total{source=*}",

@@ -1,21 +1,14 @@
 from ..sched.service import WorkflowService
 from ..sched.stats import AggregateStats
 from .engine import GenStats, ServeEngine, ServeMetrics
-from .snapshots import (
-    FabricSnapshotStore,
-    LoadedSnapshot,
-    MemorySnapshotStore,
-    SnapshotStore,
-)
+from .snapshots import FabricSnapshotStore, LoadedSnapshot
 
 __all__ = [
     "AggregateStats",
     "FabricSnapshotStore",
     "GenStats",
     "LoadedSnapshot",
-    "MemorySnapshotStore",
     "ServeEngine",
     "ServeMetrics",
-    "SnapshotStore",
     "WorkflowService",
 ]

@@ -9,11 +9,11 @@ few-shot preambles) get their KV snapshot stored; later requests skip
 prefilling them.  This is RadixAttention-style prefix caching with a
 *mined admission policy* instead of cache-everything + LRU.
 
-Where snapshots live is a seam (:mod:`repro.serve.snapshots`): the default
-:class:`MemorySnapshotStore` keeps the legacy engine-private behavior, while
-a :class:`FabricSnapshotStore` puts snapshots on the shared artifact fabric
-so N serving processes reuse each other's prefills.  On the fabric, prefill
-itself becomes a *coordinated compute*: when a ``flight``
+Snapshots live in a :class:`~repro.serve.snapshots.FabricSnapshotStore` on
+a storage backend: a bare engine gets one over an in-process
+``MemoryBackend``, while ``Client.serve_engine`` mounts one on the shared
+artifact fabric so N serving processes reuse each other's prefills.  On the
+fabric, prefill itself becomes a *coordinated compute*: when a ``flight``
 (:class:`~repro.net.flight.DistributedSingleFlight`) is wired, exactly one
 engine fleet-wide prefills a shared prefix (the leader stores the snapshot;
 followers block on the lease, then load it) — the same exactly-once
@@ -32,6 +32,7 @@ import jax
 import jax.numpy as jnp
 
 from ..configs.base import LMConfig
+from ..core.backends import MemoryBackend
 from ..core.registry import ModuleRegistry
 from ..core.risp import RISP, StoragePolicy, StoredRecord
 from ..core.workflow import ModuleRef, Workflow
@@ -40,7 +41,7 @@ from ..obs import tracing as _tracing
 from ..obs.metrics import MetricsRegistry
 from ..sched.singleflight import SingleFlight
 from ..sched.stats import AggregateStats
-from .snapshots import MemorySnapshotStore, SnapshotStore
+from .snapshots import FabricSnapshotStore
 
 
 def _chunk_id(tokens: np.ndarray) -> str:
@@ -125,19 +126,16 @@ class ServeEngine:
     chunk: int = 32
     policy: StoragePolicy = field(default_factory=RISP)
     greedy: bool = True
-    # KV-snapshot budget for the default in-memory tier: same gain-loss
-    # retention as the disk store (ignored when ``snapshots`` is passed)
-    snapshot_budget_bytes: int | None = None
-    eviction: str = "gain_loss"
     # optional shared ModuleRegistry: observed prompt chunks are recorded as
     # (non-executable) modules with prefill-cost hints, so the serving
     # workload's module universe is introspectable through the same registry
     # the workflow engines consume (repro.api.Client wires one across all
     # front doors)
     registry: ModuleRegistry | None = None
-    # where snapshots live (None -> engine-private MemorySnapshotStore);
-    # pass a FabricSnapshotStore to share prefills across processes
-    snapshots: SnapshotStore | None = None
+    # where snapshots live (None -> an engine-private store over a
+    # MemoryBackend, unbounded); pass one over a shared backend to share
+    # prefills across processes, with ``capacity_bytes`` to bound it
+    snapshots: FabricSnapshotStore | None = None
     # single-flight election over shared-prefix prefills; a
     # DistributedSingleFlight makes the election fleet-wide
     flight: SingleFlight | None = None
@@ -154,9 +152,7 @@ class ServeEngine:
                 else MetricsRegistry()
             )
         if self.snapshots is None:
-            self.snapshots = MemorySnapshotStore(
-                self.snapshot_budget_bytes, self.eviction, registry=self.metrics
-            )
+            self.snapshots = FabricSnapshotStore(MemoryBackend(), registry=self.metrics)
         # every removal path (budget eviction, fleet event, phantom probe)
         # funnels through the store's listeners: the policy's claim of the
         # snapshot dies with the snapshot — never the other way around.
@@ -170,36 +166,34 @@ class ServeEngine:
         self._t_last = 0.0
         cfg = self.cfg
 
-        # named, so a profiler shows ``jit_prefill_chunk`` / ``jit_decode_step``
-        if cfg.moe is None:
-
-            def prefill_chunk(p, t, c, l):
-                return transformer.prefill_chunk(p, cfg, t, c, l)
-
-            def decode_step(p, t, c, l):
-                return transformer.decode_step(p, cfg, t, c, l)
-
-        else:
-            # MoE: each step also adds its route totals [assignments, held]
-            # to the request's running pair on the device, ``n``, and hands
-            # back its per-layer counts; the pair is read once per request
+        if cfg.moe is not None:
             held = cfg.moe.held
 
             def totals(n, hits):
                 return n + jnp.stack([hits.sum(), hits[:, :held].sum()])
 
-            def prefill_chunk(p, t, c, l, n):
-                logits, c, l, hits = transformer.prefill_chunk(
-                    p, cfg, t, c, l, route_stats=True)
-                return logits, c, l, totals(n, hits), hits
+        def program(step):
+            """``step`` jitted under its own name, so a profiler shows
+            ``jit_prefill_chunk`` / ``jit_decode_step``.  With MoE each call
+            also adds its route totals [assignments, held] to the request's
+            running pair on the device, ``n``, and hands back its per-layer
+            counts; the pair is read once per request."""
+            if cfg.moe is None:
 
-            def decode_step(p, t, c, l, n):
-                logits, c, l, hits = transformer.decode_step(
-                    p, cfg, t, c, l, route_stats=True)
-                return logits, c, l, totals(n, hits), hits
+                def fn(p, t, c, l):
+                    return step(p, cfg, t, c, l)
 
-        self._prefill = jax.jit(prefill_chunk)
-        self._decode = jax.jit(decode_step)
+            else:
+
+                def fn(p, t, c, l, n):
+                    logits, c, l, hits = step(p, cfg, t, c, l, route_stats=True)
+                    return logits, c, l, totals(n, hits), hits
+
+            fn.__name__ = fn.__qualname__ = step.__name__
+            return jax.jit(fn)
+
+        self._prefill = program(transformer.prefill_chunk)
+        self._decode = program(transformer.decode_step)
 
     def _new_routed(self):
         """A request's route totals [assignments, held]: None without MoE."""
@@ -288,30 +282,21 @@ class ServeEngine:
             cache = transformer.init_cache(self.cfg, 1, self.max_len)
 
         t0 = time.perf_counter()
-        cache_len = jnp.asarray([cache_len_i], jnp.int32)
-        logits = None
-        routed = self._new_routed()
-        boundary: dict[int, tuple[Any, int]] = {}
         # measured recompute cost of each boundary's prefix: seconds actually
         # spent this request, plus the EMA-priced skipped part — this is what
         # gain-loss eviction will charge to re-create the snapshot
-        boundary_cost: dict[int, float] = {}
-        base_cost = start * self._chunk_prefill_s
-        cum = 0.0
-        for i in range(start, upto):
-            tok = jnp.asarray(chunks[i][None], jnp.int32)
-            tc = time.perf_counter()
-            logits, cache, cache_len, routed = self._step(
-                self._prefill, tok, cache, cache_len, routed)
-            jax.block_until_ready(logits)
-            dt = time.perf_counter() - tc
-            self._chunk_prefill_s = (
-                dt if not self._chunk_prefill_s
-                else 0.3 * dt + 0.7 * self._chunk_prefill_s
-            )
-            cum += dt
-            boundary[i + 1] = (cache, int(cache_len[0]))
-            boundary_cost[i + 1] = base_cost + cum
+        cost = start * self._chunk_prefill_s
+        boundary: dict[int, tuple[Any, int, float]] = {}
+
+        def keep(depth, cache, cache_len, dt):
+            nonlocal cost
+            cost += dt
+            boundary[depth] = (cache, int(cache_len[0]), cost)
+
+        logits, cache, cache_len, routed = self._prefill_chunks(
+            chunks, start, upto, None, cache, jnp.asarray([cache_len_i], jnp.int32),
+            self._new_routed(), on_chunk=keep,
+        )
         prefill_s = time.perf_counter() - t0
 
         # store admitted prefixes (only those whose boundary we computed)
@@ -321,12 +306,10 @@ class ServeEngine:
                 continue  # a later (uncoordinated) stage never stores
             key = prefix.key(ws)
             if prefix.depth in boundary:
-                c, ln = boundary[prefix.depth]
+                c, ln, c_s = boundary[prefix.depth]
                 with _tracing.span("serve.snapshot.save", kind="serve", key=key):
                     saved = self.snapshots.save(
-                        key, c, ln,
-                        prefill_s=boundary_cost[prefix.depth],
-                        prefix=prefix,
+                        key, c, ln, prefill_s=c_s, prefix=prefix
                     )
                 if saved:
                     stored += 1
@@ -347,14 +330,42 @@ class ServeEngine:
             routed=routed,
         )
 
+    def _prefill_chunks(self, chunks, lo, hi, logits, cache, cache_len, routed,
+                        on_chunk=None):
+        """Prefill chunks [lo, hi) one step each, blocking on every step and
+        folding its seconds into the per-chunk EMA.  ``on_chunk(depth, cache,
+        cache_len, seconds)`` sees the state after each chunk.  Returns
+        (logits, cache, cache_len, routed); ``logits`` as given when no chunk
+        runs."""
+        for i in range(lo, hi):
+            tok = jnp.asarray(chunks[i][None], jnp.int32)
+            tc = time.perf_counter()
+            logits, cache, cache_len, routed = self._step(
+                self._prefill, tok, cache, cache_len, routed)
+            jax.block_until_ready(logits)
+            dt = time.perf_counter() - tc
+            self._chunk_prefill_s = (
+                dt if not self._chunk_prefill_s
+                else 0.3 * dt + 0.7 * self._chunk_prefill_s
+            )
+            if on_chunk is not None:
+                on_chunk(i + 1, cache, cache_len, dt)
+        return logits, cache, cache_len, routed
+
     # -- generation ---------------------------------------------------------
     def generate(
         self, prompt: list[int] | np.ndarray, max_new_tokens: int = 16
     ) -> tuple[list[int], GenStats]:
         t_in = time.perf_counter()
+        prompt = np.asarray(prompt, np.int32)
+        pad = (-len(prompt)) % self.chunk
+        if len(prompt) + pad + max_new_tokens > self.max_len:
+            raise ValueError(
+                f"request needs {len(prompt) + pad} prompt rows (left-padded to "
+                f"whole chunks of {self.chunk}) + {max_new_tokens} new tokens, "
+                f"more than max_len={self.max_len}"
+            )
         with _tracing.span("serve.plan", kind="serve"):
-            prompt = np.asarray(prompt, np.int32)
-            pad = (-len(prompt)) % self.chunk
             padded = np.concatenate([np.zeros(pad, np.int32), prompt])  # left-pad
             chunks = [
                 padded[i : i + self.chunk] for i in range(0, len(padded), self.chunk)
@@ -395,21 +406,11 @@ class ServeEngine:
                 value = self._prefill_prefix(chunks, wf, rec, depth_keys, upto=n)
 
             # uncoordinated remainder: this request's private suffix
-            cache, cache_len = value.cache, value.cache_len
-            logits, routed = value.logits, value.routed
             t_ext = time.perf_counter()
-            for i in range(value.depth, n):
-                tok = jnp.asarray(chunks[i][None], jnp.int32)
-                tc = time.perf_counter()
-                logits, cache, cache_len, routed = self._step(
-                    self._prefill, tok, cache, cache_len, routed
-                )
-                jax.block_until_ready(logits)
-                dt = time.perf_counter() - tc
-                self._chunk_prefill_s = (
-                    dt if not self._chunk_prefill_s
-                    else 0.3 * dt + 0.7 * self._chunk_prefill_s
-                )
+            logits, cache, cache_len, routed = self._prefill_chunks(
+                chunks, value.depth, n, value.logits, value.cache, value.cache_len,
+                value.routed,
+            )
             prefill_s = value.prefill_s + (time.perf_counter() - t_ext)
             sp.set(n=n, skipped=value.skipped, stored=value.stored)
         stored = value.stored
@@ -419,11 +420,12 @@ class ServeEngine:
         out: list[int] = []
         first_token_s = None
         with _tracing.span("serve.decode", kind="serve"):
-            if logits is None:  # full-prompt cache hit: re-run last chunk's logits
+            if logits is None:
+                # full-prompt hit: snapshots hold no logits, so step back one
+                # chunk and run it again; its rows are overwritten in place
                 tok = jnp.asarray(chunks[-1][None], jnp.int32)
-                trimmed_cache, trimmed_len = self._trim_last_chunk(cache, cache_len)
                 logits, cache, cache_len, routed = self._step(
-                    self._prefill, tok, trimmed_cache, trimmed_len, routed
+                    self._prefill, tok, cache, cache_len - self.chunk, routed
                 )
             for _ in range(max_new_tokens):
                 nxt = int(jnp.argmax(logits[0]))
@@ -465,22 +467,6 @@ class ServeEngine:
             m.expert_assignments.inc(int(routed[0]))
             m.expert_held.inc(int(routed[1]))
         return out, stats
-
-    def _trim_last_chunk(self, cache, cache_len):
-        """Full-prefix hit: zero out the last chunk's slots and re-prefill it
-        to recover last-position logits (snapshots store caches, not logits)."""
-        ln = int(cache_len[0]) - self.chunk
-        T = jax.tree_util.tree_leaves(cache)[0].shape[2]
-        keep = (jnp.arange(T) < ln).astype(jax.tree_util.tree_leaves(cache)[0].dtype)
-
-        def zero_tail(a):
-            shape = (1, 1, T) + (1,) * (a.ndim - 3)
-            return a * keep.reshape(shape)
-
-        return (
-            jax.tree_util.tree_map(zero_tail, cache),
-            jnp.asarray([ln], jnp.int32),
-        )
 
     # -- accounting -----------------------------------------------------------
     @property

@@ -1,26 +1,20 @@
-"""The ``SnapshotStore`` seam: where a serving engine's KV snapshots live.
+"""Where a serving engine's KV snapshots live: :class:`FabricSnapshotStore`.
 
-Before this seam, :class:`~repro.serve.engine.ServeEngine` kept its prefix
-KV snapshots in a per-process dict (``_snapshots``/``_snap_records``/
-``_evictor``) — intermediate data the thesis says belongs in a *shared*
-store stayed engine-private, so N serving processes each re-prefilled the
-same system prompts.  Two implementations now stand behind one interface:
+Snapshots are first-class artifacts on any
+:class:`~repro.core.backends.StorageBackend`, encoded by the deterministic KV
+codec (:mod:`repro.core.kvcodec`): ``MemoryBackend`` for an engine-private
+store (a bare ``ServeEngine``'s default), LocalFS for single-host
+persistence, ``RemoteBackend``/``ShardedBackend`` — usually behind a
+``CachingBackend`` hot tier — for fleet-wide reuse (``Client.serve_engine``).
 
-* :class:`MemorySnapshotStore` — the extracted legacy tier: host-RAM
-  snapshots, per-process, gain-loss bounded.  Zero new failure modes.
-* :class:`FabricSnapshotStore` — snapshots as first-class artifacts on any
-  :class:`~repro.core.backends.StorageBackend` (LocalFS for single-host
-  persistence; ``RemoteBackend``/``ShardedBackend`` — usually behind a
-  ``CachingBackend`` hot tier — for fleet-wide reuse), encoded by the
-  deterministic KV codec (:mod:`repro.core.kvcodec`).
-
-Consistency discipline (the PR 8 zero-phantom contract, applied to serving):
-every way a snapshot can disappear — local gain-loss eviction, another
-process's eviction arriving on the event stream, or an authoritative absence
-discovered by a probe/load — funnels through one ``_forget`` path that drops
-the record, fires the evict listeners (the engine wires ``policy.stored``
-there), discards the catalog row, and credits the tenant ledger.  Catalog,
-policy and ledger therefore converge no matter where the eviction happened.
+Consistency discipline (the workflow store's zero-phantom contract, applied
+to serving): every way a snapshot can disappear — local gain-loss eviction,
+another process's eviction arriving on the event stream, or an authoritative
+absence discovered by a probe/load — funnels through one ``_forget`` path
+that drops the record, fires the evict listeners (the engine wires
+``policy.stored`` there), discards the catalog row, and credits the tenant
+ledger.  Catalog, policy and ledger therefore converge no matter where the
+eviction happened.
 
 Eviction is priced by **measured** prefill seconds: the engine passes the
 wall-clock cost of computing each snapshot's prefix, the codec persists it
@@ -31,7 +25,6 @@ from __future__ import annotations
 
 import threading
 import time
-from abc import ABC, abstractmethod
 from dataclasses import dataclass
 from typing import Any, Callable, Iterable
 
@@ -46,12 +39,7 @@ from ..core.store import ArtifactRecord
 from ..core.workflow import PrefixKey
 from ..obs.metrics import MetricsRegistry
 
-__all__ = [
-    "FabricSnapshotStore",
-    "LoadedSnapshot",
-    "MemorySnapshotStore",
-    "SnapshotStore",
-]
+__all__ = ["FabricSnapshotStore", "LoadedSnapshot"]
 
 
 @dataclass
@@ -70,215 +58,14 @@ def _host_tree(cache: Any) -> tuple[Any, int]:
     return host, nbytes
 
 
-class SnapshotStore(ABC):
-    """Where prefix KV snapshots live, and who learns when they die.
+class FabricSnapshotStore:
+    """KV snapshots as shared artifacts on a :class:`StorageBackend`.
 
     ``save``/``load`` move whole snapshots; ``presence_many`` answers the
     deep-prefix probe in one batched round trip (tri-state: ``None`` =
     unreachable, never treated as absent).  Evict listeners fire for *every*
     removal path — the engine keeps ``policy.stored`` consistent through
     them, exactly like the workflow store's listener contract.
-    """
-
-    def __init__(self, registry: MetricsRegistry | None = None) -> None:
-        self.metrics = registry if registry is not None else MetricsRegistry()
-        m = self.metrics
-        self._m_saves = m.counter(
-            "repro_serve_snapshot_saves_total", "KV snapshots persisted"
-        )
-        self._m_loads = m.counter(
-            "repro_serve_snapshot_loads_total", "KV snapshots restored"
-        )
-        self._m_drops = m.counter(
-            "repro_serve_snapshot_evictions_total",
-            "KV snapshots dropped, by cause",
-            labels=("source",),
-        )
-        self._m_save_s = m.histogram(
-            "repro_serve_snapshot_save_seconds", "seconds to persist one snapshot"
-        )
-        self._m_load_s = m.histogram(
-            "repro_serve_snapshot_load_seconds", "seconds to restore one snapshot"
-        )
-        m.gauge(
-            "repro_serve_snapshots", "live KV snapshots known here"
-        ).unlabeled.set_function(lambda: float(self.n_snapshots))
-        m.gauge(
-            "repro_serve_snapshot_stored_bytes", "bytes of live KV snapshots"
-        ).unlabeled.set_function(lambda: float(self.snapshot_bytes()))
-        self._listeners: list[Callable[[str], None]] = []
-
-    # -- listener plumbing (shared) -----------------------------------------
-    def add_evict_listener(self, fn: Callable[[str], None]) -> None:
-        """``fn(key)`` fires whenever ``key`` stops being loadable here —
-        local eviction, fleet eviction event, or discovered phantom."""
-        self._listeners.append(fn)
-
-    def _fire(self, key: str) -> None:
-        for fn in list(self._listeners):
-            try:
-                fn(key)
-            except Exception:  # noqa: BLE001 - listeners must not kill serving
-                pass
-
-    # -- contract ------------------------------------------------------------
-    @abstractmethod
-    def save(
-        self,
-        key: str,
-        cache: Any,
-        length: int,
-        *,
-        prefill_s: float,
-        prefix: PrefixKey | None = None,
-    ) -> bool:
-        """Persist one snapshot; False when the budget (or fabric) rejects it."""
-
-    @abstractmethod
-    def load(self, key: str) -> LoadedSnapshot | None:
-        """Restore ``key``, or None when it is gone/unreachable (a discovered
-        authoritative absence also fires the evict listeners)."""
-
-    @abstractmethod
-    def presence_many(self, keys: Iterable[str]) -> dict[str, bool | None]:
-        """Batched presence (one round trip on remote fabrics); authoritative
-        absences of locally-known snapshots fire the evict listeners."""
-
-    @abstractmethod
-    def drop(self, key: str) -> None:
-        """Explicitly evict ``key`` (no-op when unknown)."""
-
-    @abstractmethod
-    def record(self, key: str) -> ArtifactRecord | None:
-        """Bookkeeping record for ``key`` (None when unknown here)."""
-
-    @property
-    @abstractmethod
-    def n_snapshots(self) -> int: ...
-
-    @property
-    @abstractmethod
-    def n_evictions(self) -> int: ...
-
-    @abstractmethod
-    def snapshot_bytes(self) -> int: ...
-
-    def contains(self, key: str) -> bool:
-        """Presence of one key; unreachable counts as False (a redundant
-        prefill is safe, a skipped one is not) — the single-flight
-        ``stored_fn`` probe uses this."""
-        return bool(self.presence_many([key]).get(key))
-
-    def close(self) -> None:  # pragma: no cover - default teardown is empty
-        pass
-
-
-class MemorySnapshotStore(SnapshotStore):
-    """The legacy engine-private tier, extracted verbatim behind the seam:
-    host-RAM snapshot dict + gain-loss budget.  ``load`` hands back the same
-    host arrays it stored (no codec round trip — this tier trades
-    shareability for zero serialization cost)."""
-
-    def __init__(
-        self,
-        capacity_bytes: int | None = None,
-        eviction: str = "gain_loss",
-        *,
-        registry: MetricsRegistry | None = None,
-        load_bps: float = 4e9,
-    ) -> None:
-        super().__init__(registry)
-        self._snaps: dict[str, tuple[Any, int]] = {}  # key -> (host cache, len)
-        self._records: dict[str, ArtifactRecord] = {}
-        self._evictor = EvictionManager(capacity_bytes, eviction)
-        self._ctx = EvictionContext(load_bps=load_bps)
-        self._lock = threading.Lock()
-
-    def save(
-        self,
-        key: str,
-        cache: Any,
-        length: int,
-        *,
-        prefill_s: float,
-        prefix: PrefixKey | None = None,
-    ) -> bool:
-        host, nbytes = _host_tree(cache)
-        if not self._evictor.admits(nbytes):
-            return False
-        with self._lock:
-            self._snaps[key] = (host, length)
-            self._records[key] = ArtifactRecord(
-                key, nbytes, nbytes, save_s=0.0, compute_s=prefill_s
-            )
-            victims = self._evictor.select_victims(
-                self._records,
-                sum(r.nbytes_disk for r in self._records.values()),
-                ctx=self._ctx,
-                incoming=key,
-            )
-            for victim in victims:
-                self._snaps.pop(victim, None)
-                self._records.pop(victim, None)
-        for victim in victims:
-            self._m_drops.labels(source="evict").inc()
-            self._fire(victim)
-        if key in victims:
-            return False
-        self._m_saves.inc()
-        self._m_save_s.observe(0.0)
-        return True
-
-    def load(self, key: str) -> LoadedSnapshot | None:
-        t0 = time.perf_counter()
-        with self._lock:
-            entry = self._snaps.get(key)
-            rec = self._records.get(key)
-            if entry is None:
-                return None
-            if rec is not None:
-                rec.n_loads += 1
-                rec.last_used_at = time.time()
-        self._m_loads.inc()
-        load_s = time.perf_counter() - t0
-        self._m_load_s.observe(load_s)
-        return LoadedSnapshot(
-            cache=entry[0],
-            length=entry[1],
-            prefill_s=float(rec.compute_s or 0.0) if rec is not None else 0.0,
-            load_s=load_s,
-        )
-
-    def presence_many(self, keys: Iterable[str]) -> dict[str, bool | None]:
-        with self._lock:
-            return {k: k in self._snaps for k in keys}
-
-    def drop(self, key: str) -> None:
-        with self._lock:
-            known = self._snaps.pop(key, None) is not None
-            self._records.pop(key, None)
-        if known:
-            self._m_drops.labels(source="drop").inc()
-            self._fire(key)
-
-    def record(self, key: str) -> ArtifactRecord | None:
-        return self._records.get(key)
-
-    @property
-    def n_snapshots(self) -> int:
-        return len(self._snaps)
-
-    @property
-    def n_evictions(self) -> int:
-        return self._evictor.n_evictions
-
-    def snapshot_bytes(self) -> int:
-        with self._lock:
-            return sum(r.nbytes_disk for r in self._records.values())
-
-
-class FabricSnapshotStore(SnapshotStore):
-    """KV snapshots as shared artifacts on a :class:`StorageBackend`.
 
     Parameters
     ----------
@@ -315,7 +102,32 @@ class FabricSnapshotStore(SnapshotStore):
         events_from: Any = None,
         load_bps: float = 4e9,
     ) -> None:
-        super().__init__(registry)
+        self.metrics = registry if registry is not None else MetricsRegistry()
+        m = self.metrics
+        self._m_saves = m.counter(
+            "repro_serve_snapshot_saves_total", "KV snapshots persisted"
+        )
+        self._m_loads = m.counter(
+            "repro_serve_snapshot_loads_total", "KV snapshots restored"
+        )
+        self._m_drops = m.counter(
+            "repro_serve_snapshot_evictions_total",
+            "KV snapshots dropped, by cause",
+            labels=("source",),
+        )
+        self._m_save_s = m.histogram(
+            "repro_serve_snapshot_save_seconds", "seconds to persist one snapshot"
+        )
+        self._m_load_s = m.histogram(
+            "repro_serve_snapshot_load_seconds", "seconds to restore one snapshot"
+        )
+        m.gauge(
+            "repro_serve_snapshots", "live KV snapshots known here"
+        ).unlabeled.set_function(lambda: float(self.n_snapshots))
+        m.gauge(
+            "repro_serve_snapshot_stored_bytes", "bytes of live KV snapshots"
+        ).unlabeled.set_function(lambda: float(self.snapshot_bytes()))
+        self._listeners: list[Callable[[str], None]] = []
         self.backend = backend
         self.codec = codec
         self.catalog = catalog
@@ -329,7 +141,19 @@ class FabricSnapshotStore(SnapshotStore):
         if events_from is not None:
             events_from.add_event_listener(self._on_fabric_event)
 
-    # -- removal funnel ------------------------------------------------------
+    # -- listeners and the removal funnel -------------------------------------
+    def add_evict_listener(self, fn: Callable[[str], None]) -> None:
+        """``fn(key)`` fires whenever ``key`` stops being loadable here —
+        local eviction, fleet eviction event, or discovered phantom."""
+        self._listeners.append(fn)
+
+    def _fire(self, key: str) -> None:
+        for fn in list(self._listeners):
+            try:
+                fn(key)
+            except Exception:  # noqa: BLE001 - listeners must not kill serving
+                pass
+
     def _forget(self, key: str, source: str) -> None:
         """The one path out: record + catalog + ledger + listeners converge."""
         with self._lock:
@@ -370,6 +194,7 @@ class FabricSnapshotStore(SnapshotStore):
         prefill_s: float,
         prefix: PrefixKey | None = None,
     ) -> bool:
+        """Persist one snapshot; False when the budget (or fabric) rejects it."""
         host, nbytes = _host_tree(cache)
         if not self._evictor.admits(nbytes):
             return False
@@ -410,6 +235,8 @@ class FabricSnapshotStore(SnapshotStore):
         return True
 
     def load(self, key: str) -> LoadedSnapshot | None:
+        """Restore ``key``, or None when it is gone/unreachable (a discovered
+        authoritative absence also fires the evict listeners)."""
         t0 = time.perf_counter()
         try:
             tree, length, info = load_kv(self.backend, key)
@@ -452,6 +279,8 @@ class FabricSnapshotStore(SnapshotStore):
         )
 
     def presence_many(self, keys: Iterable[str]) -> dict[str, bool | None]:
+        """Batched presence (one round trip on remote fabrics); authoritative
+        absences of locally-known snapshots fire the evict listeners."""
         keys = list(keys)
         try:
             result = self.backend.exists_many(keys)
@@ -462,7 +291,14 @@ class FabricSnapshotStore(SnapshotStore):
                 self._forget(k, source="phantom")
         return result
 
+    def contains(self, key: str) -> bool:
+        """Presence of one key; unreachable counts as False (a redundant
+        prefill is safe, a skipped one is not) — the single-flight
+        ``stored_fn`` probe uses this."""
+        return bool(self.presence_many([key]).get(key))
+
     def drop(self, key: str) -> None:
+        """Explicitly evict ``key`` (no-op when unknown)."""
         self._evict(key)
 
     def record(self, key: str) -> ArtifactRecord | None:

@@ -468,7 +468,7 @@ class TestAliasContract:
         actually registered somewhere — a silent rename breaks the map and
         fails here."""
         from repro.serve.engine import ServeMetrics
-        from repro.serve.snapshots import MemorySnapshotStore
+        from repro.serve.snapshots import FabricSnapshotStore
 
         servers = [StoreServer(MemoryBackend()).start() for _ in range(2)]
         urls = ",".join(f"127.0.0.1:{s.port}" for s in servers)
@@ -478,7 +478,7 @@ class TestAliasContract:
         # serve metrics live on whichever registry the engine mounts; bind
         # them to the client registry the way Client.serve_engine() does
         ServeMetrics(client.metrics)
-        MemorySnapshotStore(registry=client.metrics)
+        FabricSnapshotStore(MemoryBackend(), registry=client.metrics)
         try:
             registered = set(client.metrics.to_doc())
             for s in servers:
